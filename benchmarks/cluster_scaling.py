@@ -103,6 +103,8 @@ def main(argv=None) -> int:
         report = quick_report()
         target = QUICK_JSON
     else:
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
         cache = None if args.no_hlo_cache else HLO_CACHE
         print(f"== cluster scaling: {DEFAULT_CLUSTER_MODELS} over "
               f"{DEFAULT_NODE_COUNTS} nodes ==")

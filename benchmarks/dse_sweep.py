@@ -139,6 +139,8 @@ def main(argv=None) -> int:
         QUICK_JSON.write_text(json.dumps(out, indent=1))
         target = QUICK_JSON
     else:
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
         from repro.core.zoo import zoo_workloads
         workloads = zoo_workloads(FULL_MODELS, FULL_PHASES)
         print(f"== DSE sweep: {grid.S} candidate specs "

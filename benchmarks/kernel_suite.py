@@ -22,8 +22,6 @@ import json
 import time
 from pathlib import Path
 
-from jax.experimental import enable_x64 as jax_enable_x64
-
 from repro.configs.a64fx_kernelsuite import (
     KERNELS, PAPER_MEAN_ABS_DIFF_PCT, PAPER_MEAN_DIFF_PCT,
     PAPER_STD_DIFF_PCT, PAPER_WITHIN_10PCT_FRACTION)
@@ -102,7 +100,7 @@ def scheduler_throughput(table: calibrate.AccuracyTable,
 def a64fx_kernel_hlo(kernel_name: str, n: int) -> str:
     """Compile one suite kernel once; both A64FX sections reuse the text."""
     from repro.configs.a64fx_kernelsuite import KERNELS_BY_NAME
-    with jax_enable_x64():
+    with calibrate.host_x64():
         x1, x2, y0 = calibrate._kernel_inputs(KERNELS_BY_NAME[kernel_name], n)
         f = calibrate._jit_kernel(kernel_name)
         return f.lower(x1, x2, y0).compile().as_text()
@@ -150,6 +148,8 @@ def main(argv=None) -> int:
                          "issue width / queue depth) against the measured "
                          "kernels and report the tuned parameter file")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     kernels = KERNELS[::4] if args.quick else KERNELS
 

@@ -66,6 +66,8 @@ def main(argv=None) -> int:
     ap.add_argument("--no-hlo-cache", action="store_true",
                     help="always recompile (ignore experiments/zoo_hlo/)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     models = args.arch
     if models is None:

@@ -144,6 +144,8 @@ def main(argv=None) -> int:
                          "zoo estimate must stay under (the gate the "
                          "unsampled pass blows). 0 disables")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     t_start = time.perf_counter()
     print(f"== sampled estimation ({A64FX_CORE.name}) ==")

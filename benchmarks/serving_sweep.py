@@ -176,6 +176,8 @@ def main() -> None:
         print(f"wrote {QUICK_JSON} ({out['wall_s']:.2f}s)")
         return
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     out = run_full(args.models, args.n)
     BENCH_JSON.write_text(json.dumps(out, indent=1))
     print(f"wrote {BENCH_JSON} ({out['wall_s']:.1f}s)")

@@ -66,21 +66,21 @@ def kernel_analytic_traffic() -> dict:
     bf2, f4 = 2, 4
     q = CHUNK
     fwd_block = (q * P * bf2            # x in
-                 + q * f4               # dt
+                 + 2 * q * f4           # dt, cs
                  + 2 * q * N * bf2      # B, C
                  + q * P * bf2          # y out
-                 + N * P * f4           # state out
-                 + f4)                  # gamma
+                 + N * P * f4)          # state out
     bwd_block = (q * P * bf2 * 2        # x, dy
-                 + q * f4               # dt
+                 + 2 * q * f4           # dt, cs
                  + 2 * q * N * bf2      # B, C
                  + N * P * f4           # dstate in
                  + q * P * bf2          # dx out
-                 + q * f4               # ddt out
-                 + 2 * q * N * f4       # dB, dC out
-                 + f4)                  # dA out
-    # jnp-side residue: inter-chunk scan + y_off (per device, fwd+bwd ~3x)
+                 + 2 * q * f4           # ddt, dcs out
+                 + 2 * q * N * f4)      # dB, dC out
+    # jnp-side residue: cumsum, gamma, inter-chunk scan + y_off (per
+    # device, fwd+bwd ~3x)
     residue = 3 * (B * nc * H * (N * P + 1) * f4        # states, gamma
+                   + B * S * H * f4                     # cs
                    + B * S * H * (N + P) * bf2)         # y_off C/x traffic
     flops_block = (2 * q * q * N        # C B^T
                    + 2 * q * q * P      # M X
@@ -93,6 +93,8 @@ def kernel_analytic_traffic() -> dict:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     jnp_t = jnp_ssd_traffic()
     ker_t = kernel_analytic_traffic()
     scale = L_LAYERS * N_MICRO

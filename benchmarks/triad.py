@@ -28,17 +28,18 @@ import subprocess
 import sys
 from pathlib import Path
 
+REPO = Path(__file__).resolve().parents[1]
 OUT = Path("experiments/bench")
 
 _CHILD = r"""
 import json, statistics, sys, time
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 n_threads = {n_threads}
 n_elems = {n_elems}
 
-mesh = jax.make_mesh((n_threads,), ("data",))
+mesh = jax.make_mesh((n_threads,), ("data",), (AxisType.Auto,))
 sh = NamedSharding(mesh, PartitionSpec("data"))
 a = jax.device_put(jnp.arange(n_elems, dtype=jnp.float64) * 1e-6, sh)
 b = jax.device_put(jnp.ones(n_elems, dtype=jnp.float64), sh)
@@ -61,11 +62,12 @@ def run_child(n_threads: int, n_elems: int, repeats: int) -> float:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_threads}"
     env["JAX_ENABLE_X64"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"        # the host is what this measures
     code = _CHILD.format(n_threads=n_threads, n_elems=n_elems,
                          repeats=repeats)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, check=True,
-                         cwd="/root/repo")
+                         cwd=REPO)
     return json.loads(out.stdout.strip().splitlines()[-1])["t"]
 
 

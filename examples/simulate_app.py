@@ -21,6 +21,7 @@ import sys
 CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 from repro.launch.dryrun import run_cell
 r = run_cell("{arch}", "{shape}", multi_pod={multi}, force=True)
 print(r["pa_report"])

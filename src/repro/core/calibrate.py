@@ -18,6 +18,7 @@ measurement of L1-resident arrays.  Same trick, same reason.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import statistics
 import time
@@ -26,8 +27,6 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-# jax.enable_x64 left the top-level namespace in jax 0.4.31+
-from jax.experimental import enable_x64 as jax_enable_x64
 
 from ..configs.a64fx_kernelsuite import KERNELS, Kernel
 from ..kernels import ref as kref
@@ -37,6 +36,15 @@ from .hwspec import CPU_HOST, HardwareSpec
 from .simulate import simulate
 
 SIZE_SCALE = 1024     # paper: iter/1000; here: n x1024 (see module docstring)
+
+
+@contextlib.contextmanager
+def host_x64():
+    """x64 on the host CPU device: the test chip here is the CPU, so its
+    arrays and jits are placed there explicitly, never on whatever
+    accelerator is JAX's default device."""
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
+        yield
 
 
 def _median_time(fn: Callable, args, repeats: int = 15) -> float:
@@ -123,7 +131,7 @@ def fit_cpu_host(n_mem: int = 1 << 21, n_fac: int = 1 << 15) -> HardwareSpec:
       paper's per-OpClass latency table, de-masked from bandwidth).
     """
     by_name = {k.name: k for k in KERNELS}
-    with jax_enable_x64():
+    with host_x64():
         startup = measure_dispatch_overhead()
 
         def t_kernel(name: str, n: int, repeats: int = 15) -> float:
@@ -287,7 +295,7 @@ def kernel_accuracy_table(hw: Optional[HardwareSpec] = None,
     hw = hw or fit_cpu_host()
     rows: List[KernelRow] = []
     programs: List[Program] = []
-    with jax_enable_x64():
+    with host_x64():
         for k in (kernels or KERNELS):
             n = k.n * size_scale
             x1, x2, y0 = _kernel_inputs(k, n)

@@ -420,7 +420,6 @@ def schedule_batch_jax(cp: CompiledProgram, knobs: O3Knobs) -> np.ndarray:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     n = cp.n
     B = knobs.batch
@@ -439,7 +438,7 @@ def schedule_batch_jax(cp: CompiledProgram, knobs: O3Knobs) -> np.ndarray:
     max_hist = max(1, int(cp.port_counts.max()))
     wmax = max(1, int(knobs.width.max()))
 
-    with enable_x64():
+    with jax.enable_x64(True):
         xs = (jnp.asarray(cp.durations), jnp.asarray(port_eff),
               jnp.asarray(costed), jnp.asarray(deps_pad),
               jnp.asarray(cp.pos_in_port), jnp.arange(n))

@@ -489,17 +489,41 @@ def _dot_cost(instr: Instr, comp: Computation):
     return flops, (M, N, K)
 
 
-def _conv_cost(instr: Instr, comp: Computation) -> float:
+def _conv_cost(instr: Instr, comp: Computation):
+    """Returns (flops, (M, N, K)), with ``K`` the inputs each output element
+    contracts.  The TPU compiler rewrites dots as convolutions: the kernel's
+    ``i``/``o`` axes sit wherever ``dim_labels`` puts them, and batch dims
+    ride as spatial dims whose window is fully base-dilated
+    (``lhs_dilate`` = window size), so only ``ceil(size / lhs_dilate)`` taps
+    of each window are real.  Without labels (hand-written HLO) the output's
+    last dim is taken as the feature axis."""
     out_elems = max(1, math.prod(instr.shape))
     rhs = instr.operands[1] if len(instr.operands) > 1 else None
-    k_elems = 1
+    rhs_shape: Tuple[int, ...] = ()
     if rhs in comp.instrs:
-        k_elems = max(1, math.prod(comp.instrs[rhs].shape))
+        rhs_shape = comp.instrs[rhs].shape
     elif rhs in comp.params:
-        k_elems = max(1, math.prod(comp.params[rhs][1]))
-    # flops ~= 2 * out * (kernel elems / out_channels)
-    out_ch = instr.shape[-1] if instr.shape else 1
-    return 2.0 * out_elems * max(1, k_elems // max(out_ch, 1))
+        rhs_shape = comp.params[rhs][1]
+    lm = re.search(r"dim_labels=\w*_(\w*)->", instr.attrs)
+    labels = lm.group(1) if lm else ""
+    if len(labels) == len(rhs_shape) and "i" in labels and "o" in labels:
+        out_ch = rhs_shape[labels.index("o")]
+        K = rhs_shape[labels.index("i")]
+        wm = re.search(r"window=\{([^}]*)\}", instr.attrs)
+        win = wm.group(1) if wm else ""
+        sizes = re.search(r"size=([\dx]+)", win)
+        dil = re.search(r"lhs_dilate=([\dx]+)", win)
+        if sizes:
+            ss = [int(v) for v in sizes.group(1).split("x")]
+            ds = ([int(v) for v in dil.group(1).split("x")] if dil
+                  else [1] * len(ss))
+            for sz, d in zip(ss, ds):
+                K *= -(-sz // max(d, 1))
+    else:
+        out_ch = instr.shape[-1] if instr.shape else 1
+        K = max(1, math.prod(rhs_shape)) // max(out_ch, 1)
+    out_ch, K = max(out_ch, 1), max(K, 1)
+    return 2.0 * out_elems * K, (max(1, out_elems // out_ch), out_ch, K)
 
 
 def _group_size(attrs: str, npart: int) -> int:
@@ -657,6 +681,7 @@ def _cost_computation(comp: Computation, comps: Dict[str, Computation],
             callee = _called(instr.attrs)
             flops = trans = 0.0
             dot_dims = None
+            is_matmul = False
             tbo: Dict[str, float] = defaultdict(float)
             vbo: Dict[str, float] = defaultdict(float)
             callee_comp = comps.get(callee) if callee else None
@@ -673,10 +698,13 @@ def _cost_computation(comp: Computation, comps: Dict[str, Computation],
                         vbo[k] += v * o.count
                     if o.dot_dims is not None:
                         dot_dims = o.dot_dims
+                    # any matmul-class op (a dot, or the convolution the TPU
+                    # compiler rewrites a dot into) makes a matmul fusion
+                    is_matmul = is_matmul or o.opclass == "matmul"
             rd_b, wr_b = _fusion_boundary_bytes(instr, comp, callee_comp)
             deps, dep_b = _dep_edges(instr.operands)
             out.append(OpStat(name, "fusion",
-                              "matmul" if dot_dims else "elementwise",
+                              "matmul" if is_matmul else "elementwise",
                               instr.dtype, flops=flops, transcendentals=trans,
                               bytes_accessed=rd_b + wr_b, read_bytes=rd_b,
                               write_bytes=wr_b, count=mult,
@@ -791,10 +819,8 @@ def _cost_computation(comp: Computation, comps: Dict[str, Computation],
                       deps=deps, dep_bytes=dep_b)
         nelems = max(1, math.prod(instr.shape))
         if cls == "matmul":
-            if opcode == "dot":
-                stat.flops, stat.dot_dims = _dot_cost(instr, comp)
-            else:
-                stat.flops = _conv_cost(instr, comp)
+            cost_fn = _dot_cost if opcode == "dot" else _conv_cost
+            stat.flops, stat.dot_dims = cost_fn(instr, comp)
         elif cls == "transcendental":
             stat.flops = float(nelems)
             stat.transcendentals = float(nelems)

@@ -665,7 +665,9 @@ def schedule_node(nc: NodeCompiled, hw: HardwareSpec, n_cores: int,
     per_core_roof = max((b / widths[pid]
                          for cb in core_busy for pid, b in enumerate(cb)
                          if b > 0), default=0.0)
-    t_serial = float(sum(durs)) * agg
+    # np.cumsum adds in order, as the single-core kernels do (Python's own
+    # sum() compensates since 3.12 and would differ in the last bit)
+    t_serial = float(np.cumsum(durs)[-1]) * agg if len(durs) else 0.0
     t_dataflow = _dataflow(durs, ports_l, indptr_l, indices_l, core_of_l,
                            sched_cmgs, ring_lat)
     sched = ScheduleResult(
@@ -1021,8 +1023,7 @@ def _node_pass_batch_jax(nb: NodeCompiledBatch, durs_cols: np.ndarray,
             return t
 
         fn = fns[key] = jax.jit(jax.vmap(one, in_axes=(0, 0, 0, 0)))
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         out = fn(jnp.asarray(window), jnp.asarray(width),
                  jnp.asarray(depth),
                  jnp.asarray(np.ascontiguousarray(durs_cols.T)))

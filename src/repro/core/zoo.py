@@ -137,12 +137,23 @@ def _traced_model(arch: str, param_dtype: str):
     return _MODEL_CACHE[key]
 
 
+def compile_target() -> Dict[str, str]:
+    """What a trace is compiled for here: the default device's platform and
+    kind, and the JAX version.  Cache keys carry it, so a trace compiled on
+    one machine is never read as another's."""
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "jax": jax.__version__}
+
+
 def hlo_cache_key(arch: str, phase: str, shape: ShapeConfig,
                   param_dtype: str) -> str:
     """Content hash of everything the cached HLO depends on: the FULL
-    reduced model config, the shape, the dtype, and ``HLO_CACHE_SCHEMA``.
-    A name-only key (the pre-schema-2 scheme) silently served stale HLO
-    when a registry config or zoo shape changed under the same name."""
+    reduced model config, the shape, the dtype, ``HLO_CACHE_SCHEMA`` and
+    the :func:`compile_target`.  A name-only key (the pre-schema-2 scheme)
+    silently served stale HLO when a registry config or zoo shape changed
+    under the same name."""
     cfg = zoo_config(arch)
     payload = json.dumps({
         "schema": HLO_CACHE_SCHEMA,
@@ -150,6 +161,7 @@ def hlo_cache_key(arch: str, phase: str, shape: ShapeConfig,
         "shape": dataclasses.asdict(shape),
         "phase": phase,
         "param_dtype": param_dtype,
+        "target": compile_target(),
     }, sort_keys=True, default=str)
     return hashlib.sha256(payload.encode()).hexdigest()[:12]
 
@@ -157,7 +169,7 @@ def hlo_cache_key(arch: str, phase: str, shape: ShapeConfig,
 def hlo_cache_path(cache_dir: Path, arch: str, phase: str,
                    shape: ShapeConfig, param_dtype: str) -> Path:
     """Cache file for one (arch, phase) cell: human-readable prefix +
-    content hash, so a config/shape/schema change misses instead of
+    content hash, so a config/shape/schema/target change misses instead of
     reading a stale trace."""
     h = hlo_cache_key(arch, phase, shape, param_dtype)
     return Path(cache_dir) / (
@@ -275,11 +287,12 @@ def serving_cost_key(arch: str, phase: str, shape: ShapeConfig,
 
     The hash covers everything the cached estimate depends on — the full
     reduced config, the shape, the core count, both dtypes, both schema
-    counters — and the ``phase`` string itself.  The phase MUST be in the
-    key: the zoo's reduced prefill and decode shapes are deliberately
-    identical (``ZOO_PREFILL``/``ZOO_DECODE``: seq 256, batch 2), so a
-    shape-only key would silently serve a prefill estimate for a decode
-    cell (the aliasing ``tests/test_serving.py`` pins against).
+    counters, the :func:`compile_target` of the trace it prices — and the
+    ``phase`` string itself.  The phase MUST be in the key: the zoo's
+    reduced prefill and decode shapes are deliberately identical
+    (``ZOO_PREFILL``/``ZOO_DECODE``: seq 256, batch 2), so a shape-only key
+    would silently serve a prefill estimate for a decode cell (the aliasing
+    ``tests/test_serving.py`` pins against).
     """
     cfg = zoo_config(arch)
     payload = json.dumps({
@@ -291,6 +304,7 @@ def serving_cost_key(arch: str, phase: str, shape: ShapeConfig,
         "n_cores": n_cores,
         "compute_dtype": compute_dtype,
         "param_dtype": param_dtype,
+        "target": compile_target(),
     }, sort_keys=True, default=str)
     return hashlib.sha256(payload.encode()).hexdigest()[:12]
 

@@ -9,8 +9,10 @@ TPU-native design (vs the CUDA flash-attention algorithm):
 * GQA is expressed in the *index map* (query head h reads KV head h // G) —
   no KV repetition through HBM.
 
-Validated in interpret mode against ``ref.flash_attention_ref`` (CPU is the
-container's only backend; TPU is the deployment target).
+Validated in interpret mode against ``ref.flash_attention_ref`` on the CPU;
+``tests/test_chip_compile.py`` compiles it for a v5e at chatglm3-6b widths.
+Forward only: ``pallas_call`` has no autodiff rule and this kernel defines no
+VJP, so it serves prefill, not training.
 """
 from __future__ import annotations
 
@@ -73,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, block_q: int = 128,
                          block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q: (B, H, Sq, D); k, v: (B, KVH, Sk, D).  Returns (B, H, Sq, D)."""
     B, H, Sq, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in ``interpret=True`` mode (the kernel
-body executes as traced python — correct semantics, no Mosaic); on a real TPU
-``interpret=False`` compiles through Mosaic.  The switch is automatic.
+This module is the one place that decides how a kernel runs: through Mosaic
+on a TPU, and in interpret mode (the kernel body executes as traced python —
+correct semantics, no Mosaic) on any other backend, such as the CPU the
+tests run on.  The kernels themselves take ``interpret`` with no default.
 """
 from __future__ import annotations
 
@@ -60,8 +61,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     Bc = Bm.reshape(B, nc, Q, H, N)
     Cc = Cm.reshape(B, nc, Q, H, N)
 
-    y_diag, states, gamma = _ssd.ssd_chunk_pallas(
-        xc, dtc, A, Bc, Cc, interpret=_interpret())
+    dA = dtc.astype(jnp.float32) * A.astype(jnp.float32)
+    cs = jnp.cumsum(dA, axis=2)                            # (B,nc,Q,H)
+    y_diag, states = _ssd.ssd_chunk_pallas(
+        xc, dtc, cs, Bc, Cc, interpret=_interpret())
+    gamma = jnp.exp(cs[:, :, -1])                          # (B,nc,H)
 
     # inter-chunk recurrence (linear in nc)
     def step(carry, inp):
@@ -77,8 +81,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     prev = jnp.moveaxis(prev, 0, 1)                        # (B,nc,H,N,P)
 
     # inter-chunk output: exp(cs_i) * C_i . prev_state
-    dA = dtc.astype(jnp.float32) * A.astype(jnp.float32)
-    cs = jnp.cumsum(dA, axis=2)                            # (B,nc,Q,H)
     y_off = jnp.einsum("bcihn,bchnp->bcihp", Cc.astype(jnp.float32), prev)
     y_off = y_off * jnp.exp(cs)[..., None]
 

@@ -77,7 +77,7 @@ def _ew_kernel(x1_ref, x2_ref, yin_ref, y_ref, *, fn):
 
 def elementwise(name: str, x1: jax.Array, x2: Optional[jax.Array] = None,
                 y0: Optional[jax.Array] = None, *, block: int = 2048,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """Run one Table-1 kernel.  1-D inputs; blocked over ``block`` lanes."""
     fn, n_in, din, dout = EXPRS[name]
     n = x1.shape[0]
@@ -106,7 +106,7 @@ def _triad_kernel(a_ref, b_ref, y_ref, *, scalar: float):
 
 
 def stream_triad(a: jax.Array, b: jax.Array, scalar: float = 3.0, *,
-                 block: int = 8192, interpret: bool = True) -> jax.Array:
+                 block: int = 8192, interpret: bool) -> jax.Array:
     """y = a + scalar * b (STREAM Triad), blocked HBM->VMEM tiles."""
     n = a.shape[0]
     block = min(block, n)

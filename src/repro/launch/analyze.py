@@ -1,7 +1,9 @@
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
-# ^ same device-count contract as dryrun.py (first lines, before jax init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ same platform and device-count contract as dryrun.py (first lines,
+# before jax init): a compile-only run on placeholder host devices.
 """PA-data deep-dive for one cell: top ops by time/bytes, trip counts,
 collective schedule — the RIKEN simulator's per-section profiling applied to
 a compiled (arch x shape x mesh) cell.

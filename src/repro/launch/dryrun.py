@@ -1,9 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
-# The dry-run (and ONLY the dry-run) needs 512 placeholder host devices so
-# jax.make_mesh can build the production meshes; smoke tests and benches see
-# the normal single device.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST be the first lines: jax locks the platform and device count on first
+# init.  The dry-run (and ONLY the dry-run) needs 512 placeholder host devices
+# so jax.make_mesh can build the production meshes; it only compiles, so it
+# names the CPU even where an accelerator is attached.  Smoke tests and
+# benches see the normal single device.
 """Multi-pod dry-run: lower + compile EVERY (arch x shape) cell on the
 single-pod (16, 16) mesh AND the 2-pod (2, 16, 16) mesh, prove it fits
 (memory_analysis), extract roofline terms (cost_analysis + collective bytes
@@ -16,7 +18,7 @@ from the partitioned HLO), and feed the RIKEN-style simulator.
 Artifacts: experiments/dryrun/<mesh>/<arch>__<shape>.json — consumed by
 EXPERIMENTS.md §Dry-run/§Roofline and by benchmarks/roofline_table.py.
 
-(No ``from __future__ import annotations`` here: the XLA_FLAGS lines above
+(No ``from __future__ import annotations`` here: the environment lines above
 must stay the first statements in the file, which PEP 236 disallows for
 __future__ imports.  Plain py3.9+ annotations only.)
 """

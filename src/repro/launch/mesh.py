@@ -22,7 +22,7 @@ import math
 import warnings
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _take_devices(devices, n: int, shape, hint: str = ""):
@@ -57,14 +57,16 @@ def make_production_mesh(*, multi_pod: bool = False,
         hint="; the dry-run must set XLA_FLAGS="
              "--xla_force_host_platform_device_count=512 "
              "before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Tiny mesh over whatever devices exist (CPU smoke tests / examples)."""
     shape = (data, model)
     devices = _take_devices(jax.devices(), data * model, shape)
-    return jax.make_mesh(shape, ("data", "model"), devices=devices)
+    return jax.make_mesh(shape, ("data", "model"), (AxisType.Auto,) * 2,
+                         devices=devices)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict:

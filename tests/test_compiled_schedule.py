@@ -114,11 +114,11 @@ def test_differential_on_kernel_suite_programs():
     <=1e-9 relative error on every kernel-suite program (it is in fact
     bit-identical; compiled HLO of the real suite kernels, no
     measurement)."""
-    from jax.experimental import enable_x64 as jax_enable_x64
+    import jax
 
     from repro.configs.a64fx_kernelsuite import KERNELS
     hw = CPU_HOST
-    with jax_enable_x64():
+    with jax.enable_x64(True):
         for k in KERNELS:
             x1, x2, y0 = calibrate._kernel_inputs(k, k.n)
             f = calibrate._jit_kernel(k.name)

@@ -107,6 +107,47 @@ ENTRY %main (p0: f32[1024,256]) -> f32[1024,256] {
     assert prog.n_partitions == 16
 
 
+_CONV_FUSION = """
+HloModule m
+
+%fused_mm (p0: bf16[{lhs}], p1: bf16[{rhs}]) -> f32[{out}] {{
+  %p0 = bf16[{lhs}]{{2,1,0}} parameter(0)
+  %p1 = bf16[{rhs}]{{2,1,0}} parameter(1)
+  ROOT %conv = f32[{out}]{{2,1,0}} convolution(%p0, %p1), {attrs}
+}}
+
+ENTRY %main (a: bf16[{lhs}], w: bf16[{rhs}]) -> f32[{out}] {{
+  %a = bf16[{lhs}]{{2,1,0}} parameter(0)
+  %w = bf16[{rhs}]{{2,1,0}} parameter(1)
+  ROOT %f = f32[{out}]{{2,1,0}} fusion(%a, %w), kind=kOutput, calls=%fused_mm
+}}
+"""
+
+
+@pytest.mark.parametrize("lhs,rhs,out,attrs,mnk", [
+    # a dot the TPU compiler wrote as a 1-wide convolution
+    ("2,256,512", "512,1024,1", "2,256,1024",
+     "window={size=1}, dim_labels=0bf_io0->0bf", (512, 1024, 512)),
+    # a batched dot: the batch dim rides as a fully base-dilated window
+    ("4,256,128", "4,256,128", "4,128,128",
+     "window={size=4 stride=3 lhs_dilate=4}, dim_labels=0fb_0io->0bf",
+     (512, 128, 256)),
+])
+def test_parser_convolution_fusion_is_matmul(lhs, rhs, out, attrs, mnk):
+    """A fusion whose matmul is a ``convolution`` (as TPU-compiled HLO
+    writes dots) is classed matmul, counts the dot's FLOPs and is priced
+    on the MXU, not the VPU."""
+    prog = parse_program(_CONV_FUSION.format(lhs=lhs, rhs=rhs, out=out,
+                                             attrs=attrs))
+    (op,) = prog.ops
+    m, n, k = mnk
+    assert op.opcode == "fusion" and op.opclass == "matmul"
+    assert op.flops == 2 * m * n * k
+    assert op.dot_dims == mnk
+    r = simulate_program(prog, TPU_V5E)
+    assert r.port_busy["mxu"] > 0.0 and r.port_busy.get("vpu", 0.0) == 0.0
+
+
 # ------------------------------------------------------------------- engine
 def _mk_op(**kw):
     base = dict(name="x", opcode="dot", opclass="matmul", dtype="bf16")

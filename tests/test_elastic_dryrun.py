@@ -22,9 +22,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=128"
 import json
 import jax
+from jax.sharding import AxisType
 from repro.launch.cell import build_cell
 
-mesh = jax.make_mesh((8, 16), ("data", "model"), devices=jax.devices())
+mesh = jax.make_mesh((8, 16), ("data", "model"), (AxisType.Auto,) * 2,
+                     devices=jax.devices())
 cell = build_cell("{arch}", "{shape}", mesh)
 compiled = cell.lower().compile()
 m = compiled.memory_analysis()

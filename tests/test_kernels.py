@@ -3,8 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-# jax.enable_x64 left the top-level namespace in jax 0.4.31+
-from jax.experimental import enable_x64 as jax_enable_x64
 
 pytestmark = pytest.mark.slow        # every test here compiles through jax
 
@@ -107,7 +105,7 @@ def test_ssd_scan_initial_state(key):
 # ------------------------------------------------- the paper's kernel suite
 @pytest.mark.parametrize("name", sorted(EXPRS))
 def test_elementwise_kernel_vs_ref(name, key):
-    with jax_enable_x64():
+    with jax.enable_x64(True):
         fn, n_in, din, dout = EXPRS[name]
         n = 4096
         from repro.kernels.stream import _DTYPES

@@ -103,11 +103,11 @@ def test_one_core_under_own_topology_matches_when_uncontended():
 def test_differential_one_core_on_kernel_suite_programs():
     """Acceptance: the 1-core node path is bit-identical to the
     single-core scheduler on every compiled kernel-suite program."""
-    from jax.experimental import enable_x64 as jax_enable_x64
+    import jax
 
     from repro.configs.a64fx_kernelsuite import KERNELS
     hw = CPU_HOST
-    with jax_enable_x64():
+    with jax.enable_x64(True):
         for k in KERNELS:
             x1, x2, y0 = calibrate._kernel_inputs(k, k.n)
             f = calibrate._jit_kernel(k.name)
@@ -304,10 +304,10 @@ def test_node_acceptance_on_compiled_kernel_suite():
     topology, 1-core node == single-core schedule bit-for-bit (degenerate
     topo) and the 48-core estimate is strictly between the single-core
     and zero-contention bounds."""
-    from jax.experimental import enable_x64 as jax_enable_x64
+    import jax
 
     from repro.configs.a64fx_kernelsuite import KERNELS_BY_NAME
-    with jax_enable_x64():
+    with jax.enable_x64(True):
         for name in ("add", "mul", "exp"):
             k = KERNELS_BY_NAME[name]
             n = k.n * calibrate.SIZE_SCALE       # DRAM-resident

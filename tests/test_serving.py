@@ -428,6 +428,25 @@ def test_serving_cost_key_phase_distinct():
         == (ZOO_DECODE.seq_len, ZOO_DECODE.global_batch)
 
 
+@pytest.mark.parametrize("field", ["platform", "device_kind", "jax"])
+def test_cache_keys_name_the_compile_target(field, monkeypatch):
+    """A trace compiled for one target (backend, chip kind, JAX version) is
+    never read as another's: keys differing only in target differ."""
+    shape = zoo.ZOO_SHAPES["decode"]
+
+    def keys():
+        return (zoo.hlo_cache_key("chatglm3-6b", "decode", shape, "float32"),
+                zoo.serving_cost_key("chatglm3-6b", "decode", shape, 48,
+                                     "f32", "float32"))
+
+    here = keys()
+    assert keys() == here
+    there = dict(zoo.compile_target(), **{field: "elsewhere"})
+    monkeypatch.setattr(zoo, "compile_target", lambda: there)
+    elsewhere = keys()
+    assert elsewhere[0] != here[0] and elsewhere[1] != here[1]
+
+
 def test_hlo_cache_key_and_path_phase_distinct(tmp_path):
     from repro.configs.shapes import ZOO_PREFILL
     shape = dataclasses.replace(ZOO_PREFILL, name="alias")

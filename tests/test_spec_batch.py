@@ -501,7 +501,7 @@ def test_spec_batch_differential_on_kernel_suite_programs():
     ports) are bit-identical to the scalar per-spec path on every
     compiled kernel-suite program — real XLA HLO, not just synthetic
     DAGs."""
-    from jax.experimental import enable_x64 as jax_enable_x64
+    import jax
 
     from repro.configs.a64fx_kernelsuite import KERNELS
     from repro.core import calibrate
@@ -509,7 +509,7 @@ def test_spec_batch_differential_on_kernel_suite_programs():
 
     rng = random.Random(11)
     grid = random_grid(rng, 3, base=CPU_HOST)
-    with jax_enable_x64():
+    with jax.enable_x64(True):
         for k in KERNELS:
             x1, x2, y0 = calibrate._kernel_inputs(k, k.n)
             f = calibrate._jit_kernel(k.name)
