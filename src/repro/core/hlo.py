@@ -22,6 +22,8 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from .stats import region_of
+
 DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
     "s32": 4, "u32": 4, "s64": 8, "u64": 8, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -133,6 +135,10 @@ class OpStat:
     # sizes, split evenly when one operand resolves to several producers.
     # core.memory turns these into reuse-distance-routed reads.
     dep_bytes: List[float] = field(default_factory=list)
+    # the instruction's ``metadata={op_name=...}``: the JAX name stack it was
+    # traced under, which names its region (``stats.region_of``); see
+    # ``op_names`` for instructions XLA added
+    op_name: str = ""
 
 
 @dataclass
@@ -220,6 +226,55 @@ _GROUPS_ITOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=")
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
 _CONST_INT_RE = re.compile(r"s(?:32|64)\[\]\s+constant\((\d+)\)")
 _NPART_RE = re.compile(r"num_partitions=(\d+)")
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+
+
+def _op_name(attrs: str) -> str:
+    m = _OP_NAME_RE.search(attrs)
+    return m.group(1) if m else ""
+
+
+def _op_names(comps: Dict[str, "Computation"]) -> Dict[str, str]:
+    """{instruction name: op_name} over ``comps``.  XLA's own instructions
+    (layout copies, prefetches, weight casts, loop-invariant broadcasts)
+    carry no metadata, or a path cut short: an instruction whose op_name
+    names no region takes that of its first user that names one, else that
+    of its first operand that does, within its computation.  The bodies of
+    fusions keep their own: no trace or engine sees their instructions."""
+    fused = {_called(i.attrs) for c in comps.values()
+             for i in c.instrs.values() if i.opcode == "fusion"}
+    out: Dict[str, str] = {}
+    for comp in comps.values():
+        own = {n: _op_name(i.attrs) for n, i in comp.instrs.items()}
+        if comp.name in fused:
+            out.update(own)
+            continue
+        got = {n: v for n, v in own.items() if region_of(v)[0]}
+        users: Dict[str, List[str]] = defaultdict(list)
+        for n in comp.order:
+            for o in comp.instrs[n].operands:
+                users[o].append(n)
+        for n in reversed(comp.order):
+            if n not in got:
+                u = next((u for u in users[n] if u in got), None)
+                if u is not None:
+                    got[n] = got[u]
+        for n in comp.order:
+            if n not in got:
+                o = next((o for o in comp.instrs[n].operands if o in got),
+                         None)
+                if o is not None:
+                    got[n] = got[o]
+        out.update((n, got.get(n, own[n])) for n in comp.order)
+    return out
+
+
+def op_names(text: str) -> Dict[str, str]:
+    """{instruction name: op_name} over every computation of a module, fusion
+    bodies and loop bodies included: the one map from the instructions a
+    device trace names to the regions they were traced in
+    (``stats.region_of``), shared by the simulator's sections."""
+    return _op_names(parse_computations(text)[0])
 
 
 def _parse_type(s: str) -> Tuple[str, Tuple[int, ...], float, bool, float]:
@@ -846,4 +901,7 @@ def parse_program(text: str) -> Program:
     ops: List[OpStat] = []
     if entry in comps:
         _cost_computation(comps[entry], comps, npart, 1.0, ops, True)
+    names = _op_names(comps)
+    for o in ops:
+        o.op_name = names.get(o.name, "")
     return Program(ops=ops, entry=entry, n_partitions=npart)

@@ -19,6 +19,7 @@ from .hlo import Program
 from .node import NodeResult
 from .roofline import Roofline
 from .schedule import ScheduleResult
+from .stats import Stats
 
 
 def _fmt_t(s: float) -> str:
@@ -173,7 +174,8 @@ def _node_section(node: NodeResult) -> List[str]:
 def pa_report(rf: Roofline, eng: EngineResult, prog: Program,
               title: str = "", sched: Optional[ScheduleResult] = None,
               engine_mode: str = "occupancy",
-              node: Optional[NodeResult] = None) -> str:
+              node: Optional[NodeResult] = None,
+              sections: Optional[Stats] = None) -> str:
     lines = []
     lines.append(f"== PA report {title} ==")
     # headline matches SimReport.t_est: node-derived in node mode,
@@ -219,6 +221,9 @@ def pa_report(rf: Roofline, eng: EngineResult, prog: Program,
         lines.extend(_schedule_section(sched))
     if node is not None:
         lines.extend(_node_section(node))
+    if sections is not None and set(sections.sections()) - {"other"}:
+        lines.append("  sections (program regions, occupancy engine):")
+        lines.extend("    " + ln for ln in sections.report().splitlines())
     lines.append("  hints:")
     for s in suggestions(rf, eng, prog):
         lines.append(f"    - {s}")

@@ -12,10 +12,11 @@ execution-cycle estimate + PA data, before the target hardware exists.
 from __future__ import annotations
 
 import json
+from collections import defaultdict
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
-from .cost import cost_program
+from .cost import OpTime, cost_program
 from .engine import EngineResult, simulate_program
 from .hlo import Program, parse_program
 from .hwspec import HardwareSpec, NodeTopology, TPU_V5E
@@ -24,6 +25,7 @@ from .pa import pa_report
 from .roofline import Roofline, roofline_from_program
 from .sample import SampledNodeResult, SamplingConfig, sampled_schedule_node
 from .schedule import ScheduleResult, schedule_program
+from .stats import REGIONS, Stats, region_of
 
 
 @dataclass
@@ -51,6 +53,10 @@ class SimReport:
     node: Optional[NodeResult] = None
     # sampled node estimation (engine="node" + sampling=; DESIGN.md §18)
     sampled: Optional[SampledNodeResult] = None
+    # section statistics over the program's regions (``stats.REGIONS`` and
+    # ``other``): the occupancy engine on each region's ops alone;
+    # ``__global__`` holds the whole program
+    sections: Optional[Stats] = None
 
     @property
     def t_est(self) -> float:
@@ -170,6 +176,32 @@ def _cost_stats(compiled) -> Optional[Dict[str, float]]:
         return None
 
 
+def _put(stats: Stats, section: str, eng: EngineResult) -> None:
+    stats.add("t_est_s", eng.t_est, section=section)
+    stats.add("t_serial_s", eng.t_serial, section=section)
+    for port, t in eng.port_busy.items():
+        stats.add(f"busy_{port}_s", t, section=section)
+
+
+def _region_sections(prog: Program, hw: HardwareSpec,
+                    costed: List[Optional[OpTime]], whole: EngineResult,
+                    compute_dtype: Optional[str] = None) -> Stats:
+    """The paper's section statistics, with the program's regions as the
+    sections: the occupancy engine over each region's share of ``costed``
+    (ops of no region make ``other``), and ``whole`` as ``__global__``."""
+    share: Dict[str, List[OpTime]] = defaultdict(list)
+    for ot in costed:
+        if ot is not None:
+            share[region_of(ot.op.op_name)[0] or "other"].append(ot)
+    stats = Stats()
+    _put(stats, "__global__", whole)
+    for name in (*REGIONS, "other"):
+        if share[name]:
+            _put(stats, name, simulate_program(
+                prog, hw, compute_dtype=compute_dtype, costed=share[name]))
+    return stats
+
+
 def simulate(compiled, hw: HardwareSpec = TPU_V5E, n_chips: int = 1,
              model_flops_global: float = 0.0, compute_dtype: str = "bf16",
              title: str = "", engine: str = "occupancy",
@@ -230,6 +262,7 @@ def simulate(compiled, hw: HardwareSpec = TPU_V5E, n_chips: int = 1,
     costed = cost_program(prog, hw, compute_dtype=compute_dtype)
     eng = simulate_program(prog, hw, compute_dtype=compute_dtype,
                            costed=costed)
+    sections = _region_sections(prog, hw, costed, eng, compute_dtype)
     # the PA report below renders the timeline/critical path, so ask the
     # scheduler for full detail up front (sweeps use the fast path instead)
     sched = (schedule_program(prog, hw, compute_dtype=compute_dtype,
@@ -259,7 +292,8 @@ def simulate(compiled, hw: HardwareSpec = TPU_V5E, n_chips: int = 1,
     return SimReport(hw=hw.name, n_chips=n_chips, roofline=rf, engine=eng,
                      program_summary=summary,
                      pa=pa_report(rf, eng, prog, title, sched=sched,
-                                  engine_mode=engine, node=node),
+                                  engine_mode=engine, node=node,
+                                  sections=sections),
                      xla_cost_analysis=cost, memory_analysis=mem,
                      schedule=sched, engine_mode=engine, program=prog,
-                     node=node, sampled=sampled)
+                     node=node, sampled=sampled, sections=sections)

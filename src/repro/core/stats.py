@@ -5,14 +5,50 @@ statistics (stats over a program region), implemented via a two-pass script.
 Here sections are first-class: a ``Stats`` object holds named counters;
 ``section(name)`` scopes every update (and wall time) to that region, and
 ``delta(a, b)`` gives region differences without any two-pass dance.
+
+The regions of a compiled program are the ``jax.named_scope`` names the
+model's train step runs under (``REGIONS``); :func:`region_of` reads them
+back from an HLO instruction's ``op_name``, so the device trace and the
+simulator attribute time to the same regions.
 """
 from __future__ import annotations
 
 import contextlib
 import json
+import re
 import time
 from collections import defaultdict
-from typing import Dict, Iterator
+from typing import Dict, Iterator, Optional, Tuple
+
+# The named scopes of the train step (models/lm.py, models/ssm.py,
+# kernels/ops.py, train/trainer.py).  An op in none of them is ``other``.
+REGIONS = ("embed", "layers", "block_norm", "mixer.in_proj", "mixer.conv",
+           "mixer.ssd_chunk", "mixer.ssd_state", "mixer.gate",
+           "mixer.out_proj", "head", "optimizer")
+_REGION_SET = frozenset(REGIONS)
+_SPLIT = re.compile(r"[/;()]")
+
+
+def region_of(op_name: str) -> Tuple[Optional[str], str]:
+    """(region, phase) of an HLO ``metadata={op_name=...}`` path.
+
+    The region is the innermost path component that names a region, also
+    when a transform wraps it (``transpose(jvp(layers))``).  The phase is
+    ``recompute`` inside a rematerialised body (``rematted_computation``),
+    else ``backward`` under a transpose, else ``forward``; the optimizer
+    region is its own phase.
+    """
+    region = None
+    for part in _SPLIT.split(op_name):
+        if part in _REGION_SET:
+            region = part
+    if region == "optimizer":
+        return region, "optimizer"
+    if "rematted_computation" in op_name:
+        return region, "recompute"
+    if "transpose(" in op_name:
+        return region, "backward"
+    return region, "forward"
 
 
 class Stats:
@@ -48,7 +84,8 @@ class Stats:
                 if all(s == "__global__" for s in self._stack):
                     self._sections["__global__"]["wall_s"] += dt
 
-    def add(self, counter: str, value: float = 1.0) -> None:
+    def add(self, counter: str, value: float = 1.0,
+            section: Optional[str] = None) -> None:
         """Adds to EVERY active section (the full nesting stack).
 
         Enclosing sections see their nested sections' counters — a
@@ -56,7 +93,14 @@ class Stats:
         the total — and ``__global__`` (always the stack's base) keeps
         accumulating across sections.  A section re-entered recursively
         on the stack is credited once.
+
+        With ``section``, adds to that section alone, whatever is active:
+        for counters computed after the fact, such as a simulated region's
+        time.
         """
+        if section is not None:
+            self._sections[section][counter] += value
+            return
         seen = set()
         for name in self._stack:
             if name not in seen:
@@ -92,5 +136,3 @@ class Stats:
         return json.dumps({k: dict(v) for k, v in self._sections.items()},
                           indent=1, sort_keys=True)
 
-
-GLOBAL_STATS = Stats()

@@ -65,7 +65,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     cs = jnp.cumsum(dA, axis=2)                            # (B,nc,Q,H)
     y_diag, states = _ssd.ssd_chunk_pallas(
         xc, dtc, cs, Bc, Cc, interpret=_interpret())
-    gamma = jnp.exp(cs[:, :, -1])                          # (B,nc,H)
 
     # inter-chunk recurrence (linear in nc)
     def step(carry, inp):
@@ -73,19 +72,22 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         new = carry * g[..., None, None] + s_c
         return new, carry
 
-    init = (jnp.zeros((B, H, N, P), jnp.float32) if initial_state is None
-            else jnp.moveaxis(initial_state, -1, -2).astype(jnp.float32))
-    final, prev = jax.lax.scan(step, init,
-                               (jnp.moveaxis(states, 1, 0),
-                                jnp.moveaxis(gamma, 1, 0)))
-    prev = jnp.moveaxis(prev, 0, 1)                        # (B,nc,H,N,P)
+    with jax.named_scope("mixer.ssd_state"):
+        gamma = jnp.exp(cs[:, :, -1])                      # (B,nc,H)
+        init = (jnp.zeros((B, H, N, P), jnp.float32) if initial_state is None
+                else jnp.moveaxis(initial_state, -1, -2).astype(jnp.float32))
+        final, prev = jax.lax.scan(step, init,
+                                   (jnp.moveaxis(states, 1, 0),
+                                    jnp.moveaxis(gamma, 1, 0)))
+        prev = jnp.moveaxis(prev, 0, 1)                    # (B,nc,H,N,P)
 
-    # inter-chunk output: exp(cs_i) * C_i . prev_state
-    y_off = jnp.einsum("bcihn,bchnp->bcihp", Cc.astype(jnp.float32), prev)
-    y_off = y_off * jnp.exp(cs)[..., None]
+        # inter-chunk output: exp(cs_i) * C_i . prev_state
+        y_off = jnp.einsum("bcihn,bchnp->bcihp", Cc.astype(jnp.float32),
+                           prev)
+        y_off = y_off * jnp.exp(cs)[..., None]
 
-    y = (y_diag.astype(jnp.float32) + y_off).reshape(B, Lp, H, P)[:, :L]
-    return y.astype(x.dtype), jnp.moveaxis(final, -1, -2).astype(x.dtype)
+        y = (y_diag.astype(jnp.float32) + y_off).reshape(B, Lp, H, P)[:, :L]
+        return y.astype(x.dtype), jnp.moveaxis(final, -1, -2).astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("name", "block"))

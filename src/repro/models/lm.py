@@ -210,7 +210,8 @@ class LM:
     def _embed_inputs(self, params, batch: dict, mode: str) -> jax.Array:
         cfg = self.cfg
         tokens = batch["tokens"]
-        x = embed_tokens(params["embed"], tokens, cfg)
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], tokens, cfg)
         if cfg.family == "vlm" and mode != "decode":
             img = batch["img_embeds"].astype(x.dtype)
             n_img = img.shape[1]
@@ -320,15 +321,18 @@ class LM:
         def body(h, scanned):
             lp, lc = scanned
             lp = constrain_params(lp, layer_specs)
-            a_in = apply_norm(lp["ln"], h)
+            with jax.named_scope("block_norm"):
+                a_in = apply_norm(lp["ln"], h)
             a, new_lc = apply_mamba(lp["mamba"], a_in, cfg, mode=mode,
                                     cache=lc, impl=self.ssd_impl)
-            h = lsc(h + a, "batch", "rseq", "embed")
+            with jax.named_scope("block_norm"):
+                h = lsc(h + a, "batch", "rseq", "embed")
             return h, new_lc
 
         if cfg.remat == "full" and mode == "train":
             body = jax.checkpoint(body)
-        x, caches = jax.lax.scan(body, x, (params["layers"], cache))
+        with jax.named_scope("layers"):
+            x, caches = jax.lax.scan(body, x, (params["layers"], cache))
         return x, 0.0, caches
 
     def _hybrid_stack(self, params, x, mode, cache, pos):
@@ -413,14 +417,17 @@ class LM:
         else:
             x, aux, caches = self._dense_stack(params, x, mode, cache, pos,
                                                cross_x)
-        x = apply_norm(params["final_norm"], x)
-        logits = logits_from_hidden(params["embed"], x, cfg)
+        with jax.named_scope("head"):
+            x = apply_norm(params["final_norm"], x)
+            logits = logits_from_hidden(params["embed"], x, cfg)
         return logits, aux, caches
 
     # ------------------------------------------------------------ entry points
     def loss_fn(self, params, batch: dict):
         logits, aux, _ = self.forward(params, batch, "train")
-        loss = next_token_loss(logits, batch["tokens"], self.cfg.vocab_size)
+        with jax.named_scope("head"):
+            loss = next_token_loss(logits, batch["tokens"],
+                                   self.cfg.vocab_size)
         return loss + aux, {"ce": loss, "aux": aux}
 
     def prefill_fn(self, params, batch: dict, max_seq: Optional[int] = None):

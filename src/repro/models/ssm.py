@@ -217,23 +217,24 @@ def apply_mamba(p: dict, x_in: jax.Array, cfg: ModelConfig, *, mode: str,
     nh = s.n_heads(d)
     G, N, Pd = s.n_groups, s.d_state, s.head_dim
 
-    z = jnp.einsum("bsd,de->bse", x_in, p["wz"])
-    xr = jnp.einsum("bsd,de->bse", x_in, p["wx"])
-    Br = jnp.einsum("bsd,de->bse", x_in, p["wB"])
-    Cr = jnp.einsum("bsd,de->bse", x_in, p["wC"])
-    dt_raw = jnp.einsum("bsd,de->bse", x_in, p["wdt"])
+    with jax.named_scope("mixer.in_proj"):
+        z = jnp.einsum("bsd,de->bse", x_in, p["wz"])
+        xr = jnp.einsum("bsd,de->bse", x_in, p["wx"])
+        Br = jnp.einsum("bsd,de->bse", x_in, p["wB"])
+        Cr = jnp.einsum("bsd,de->bse", x_in, p["wC"])
+        dt_raw = jnp.einsum("bsd,de->bse", x_in, p["wdt"])
     xr = lsc(xr, "batch", "seq", "inner")
 
     cx = cache.get("conv_x") if cache else None
     cB = cache.get("conv_B") if cache else None
     cC = cache.get("conv_C") if cache else None
-    xr, ncx = causal_conv(xr, p["conv_x_w"], p["conv_x_b"], cx)
-    Br, ncB = causal_conv(Br, p["conv_B_w"], p["conv_B_b"], cB)
-    Cr, ncC = causal_conv(Cr, p["conv_C_w"], p["conv_C_b"], cC)
-
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("mixer.conv"):
+        xr, ncx = causal_conv(xr, p["conv_x_w"], p["conv_x_b"], cx)
+        Br, ncB = causal_conv(Br, p["conv_B_w"], p["conv_B_b"], cB)
+        Cr, ncC = causal_conv(Cr, p["conv_C_w"], p["conv_C_b"], cC)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + p["dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
     # shard SSD heads on 'model': the (B, nc, H, Q, Q) intra-chunk matrices
     # (the memory hot-spot the Pallas kernel tiles away) ride the tensor axis
@@ -255,10 +256,12 @@ def apply_mamba(p: dict, x_in: jax.Array, cfg: ModelConfig, *, mode: str,
         init = cache["state"] if cache else None
         if impl == "pallas":
             hpg = nh // G
-            Bh = jnp.repeat(Bm, hpg, axis=2)              # (B,S,H,N)
-            Ch = jnp.repeat(Cm, hpg, axis=2)
-            y, final_state = ssd_pallas_sharded(xh, dt, A, Bh, Ch, s.chunk,
-                                                initial_state=init)
+            with jax.named_scope("mixer.ssd_chunk"):
+                Bh = jnp.repeat(Bm, hpg, axis=2)          # (B,S,H,N)
+                Ch = jnp.repeat(Cm, hpg, axis=2)
+                y, final_state = ssd_pallas_sharded(xh, dt, A, Bh, Ch,
+                                                    s.chunk,
+                                                    initial_state=init)
         else:
             y, final_state = ssd_chunked(xh, dt, A, Bm, Cm, s.chunk, init)
         new_cache = None
@@ -266,10 +269,12 @@ def apply_mamba(p: dict, x_in: jax.Array, cfg: ModelConfig, *, mode: str,
             new_cache = {"conv_x": ncx, "conv_B": ncB, "conv_C": ncC,
                          "state": final_state}
 
-    y = y + xh * p["D"].astype(y.dtype)[:, None]
-    y = y.reshape(Bsz, S, di)
-    y = rms_norm_gated(y, p["norm"], z)
-    out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+    with jax.named_scope("mixer.gate"):
+        y = y + xh * p["D"].astype(y.dtype)[:, None]
+        y = y.reshape(Bsz, S, di)
+        y = rms_norm_gated(y, p["norm"], z)
+    with jax.named_scope("mixer.out_proj"):
+        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     return lsc(out, "batch", "rseq", "embed"), new_cache
 
 

@@ -72,7 +72,8 @@ def make_train_step(model: LM, run: RunConfig, rules: Optional[MeshRules]):
             if n_micro == 1:
                 (loss, metrics), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(compute_params, batch)
-                grads = cast_tree(grads, jnp.float32)
+                with jax.named_scope("optimizer"):
+                    grads = cast_tree(grads, jnp.float32)
             else:
                 def micro(batch_slice, acc):
                     (l, m), g = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -100,9 +101,10 @@ def make_train_step(model: LM, run: RunConfig, rules: Optional[MeshRules]):
                 grads = grad_compress.compressed_pod_sync(grads, rules.mesh)
 
             from .optimizer import clip_by_global_norm
-            grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
-            new_params, new_opt = opt_update(grads, opt_state, params,
-                                             run.learning_rate)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
+                new_params, new_opt = opt_update(grads, opt_state, params,
+                                                 run.learning_rate)
             out_metrics = {"loss": loss, "grad_norm": gnorm, **metrics}
             return new_params, new_opt, out_metrics
 

@@ -8,6 +8,8 @@ suite lives in this one file: the topology is described inside a fixture,
 never at import, so parallel test workers collect the same tests and only
 the worker given this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -96,3 +98,50 @@ def test_tpu_compiled_mlp_gradient_is_priced_on_the_mxu(one_chip):
     # x@w1 forward plus the two weight gradients: three 2048^3 matmuls
     assert sum(o.flops * o.count for o in big) >= 3 * 2 * n ** 3
     assert rep.engine.bound_by == "mxu"
+
+
+def test_train_step_regions_survive_the_v5e_compiler(one_chip, monkeypatch):
+    """Two Mamba-2 layers at mamba2-1.3b's widths (one would unroll the
+    layer loop), their train step compiled for the v5e with the Pallas SSD
+    kernel: the forward, recomputed and backward kernel calls sit in
+    ``mixer.ssd_chunk``, every matmul in a region, and the simulator's
+    sections split the step's serial time."""
+    import dataclasses
+
+    from repro.configs import ARCHS, RunConfig, ShapeConfig
+    from repro.core.hlo import op_names
+    from repro.core.stats import REGIONS, region_of
+    from repro.kernels import ops
+    from repro.launch.train import build_training
+    from repro.models.lm import build_model
+    from repro.train.optimizer import OptConfig, adamw_init
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mc = dataclasses.replace(ARCHS["mamba2-1.3b"], n_layers=2, remat="full")
+    model = build_model(mc, ssd_impl="pallas")
+    run = RunConfig(model=mc, shape=ShapeConfig("t", 512, 1, "train"),
+                    param_dtype="float32", compute_dtype="float32")
+    jitted, _, _ = build_training(model, run)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    opt = jax.eval_shape(lambda: adamw_init(params, OptConfig()))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((1, 512), jnp.int32)})
+    compiled = jitted.lower(on_chip(params), on_chip(opt), batch).compile()
+    text = compiled.as_text()
+    names = op_names(text)
+    calls = [n for n, line in ((m.group(1), m.group(0)) for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*tpu_custom_call.*$", text, re.M))]
+    assert sorted(region_of(names[n]) for n in calls) == [
+        ("mixer.ssd_chunk", "backward"), ("mixer.ssd_chunk", "forward"),
+        ("mixer.ssd_chunk", "recompute")]
+    rep = simulate(compiled, hw=TPU_V5E, n_chips=1, compute_dtype="f32")
+    matmuls = [o for o in rep.program.ops if o.opclass == "matmul"]
+    assert matmuls and all(region_of(o.op_name)[0] for o in matmuls)
+    assert {region_of(o.op_name)[0] for o in rep.program.ops} >= set(REGIONS)
+    s = rep.sections
+    assert sum(s.get("t_serial_s", p) for p in s.sections()) == \
+        pytest.approx(rep.engine.t_serial, rel=1e-12)
