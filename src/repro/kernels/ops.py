@@ -42,11 +42,12 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
              initial_state: Optional[jax.Array] = None):
     """Full SSD scan = Pallas intra-chunk kernel + jnp inter-chunk recurrence.
 
-    x: (B,L,H,P); dt: (B,L,H) post-softplus; A: (H,); Bm, Cm: (B,L,H,N)
-    (head-broadcast).  Returns (y, final_state (B,H,P,N)).
+    x: (B,L,H,P); dt: (B,L,H) post-softplus; A: (H,); Bm, Cm: (B,L,G,N)
+    at the group count, H % G == 0.  Returns (y, final_state (B,H,P,N)).
     """
     B, L, H, P = x.shape
-    N = Bm.shape[-1]
+    G, N = Bm.shape[-2:]
+    hpg = H // G
     Q = min(chunk, L)
     pad = (-L) % Q
     if pad:
@@ -58,8 +59,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     nc = Lp // Q
     xc = x.reshape(B, nc, Q, H, P)
     dtc = dt.reshape(B, nc, Q, H)
-    Bc = Bm.reshape(B, nc, Q, H, N)
-    Cc = Cm.reshape(B, nc, Q, H, N)
+    Bc = Bm.reshape(B, nc, Q, G, N)
+    Cc = Cm.reshape(B, nc, Q, G, N)
 
     dA = dtc.astype(jnp.float32) * A.astype(jnp.float32)
     cs = jnp.cumsum(dA, axis=2)                            # (B,nc,Q,H)
@@ -81,10 +82,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                                     jnp.moveaxis(gamma, 1, 0)))
         prev = jnp.moveaxis(prev, 0, 1)                    # (B,nc,H,N,P)
 
-        # inter-chunk output: exp(cs_i) * C_i . prev_state
-        y_off = jnp.einsum("bcihn,bchnp->bcihp", Cc.astype(jnp.float32),
-                           prev)
-        y_off = y_off * jnp.exp(cs)[..., None]
+        # inter-chunk output: exp(cs_i) * C_i . prev_state, one
+        # (Q,N)x(N,hpg·P) product per group
+        y_off = jnp.einsum("bcign,bcgknp->bcigkp", Cc.astype(jnp.float32),
+                           prev.reshape(B, nc, G, hpg, N, P))
+        y_off = y_off.reshape(B, nc, Q, H, P) * jnp.exp(cs)[..., None]
 
         y = (y_diag.astype(jnp.float32) + y_off).reshape(B, Lp, H, P)[:, :L]
         return y.astype(x.dtype), jnp.moveaxis(final, -1, -2).astype(x.dtype)

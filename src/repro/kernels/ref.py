@@ -33,7 +33,8 @@ def ssd_ref(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     """Sequential (token-by-token) SSD recurrence — the ground truth the
     chunked algorithm and the Pallas kernel must reproduce.
 
-    x: (B,L,H,P); dt: (B,L,H); A: (H,); Bm,Cm: (B,L,H,N) (head-broadcast).
+    x: (B,L,H,P); dt: (B,L,H); A: (H,); Bm,Cm: (B,L,H,N), one per head
+    (groups repeated to their heads).
     Returns (y (B,L,H,P), final_state (B,H,P,N)).
     """
     Bsz, L, H, P = x.shape

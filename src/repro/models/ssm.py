@@ -7,6 +7,7 @@ for the ``kernels.ssd_scan`` Pallas kernel.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -17,31 +18,43 @@ from ..parallel.sharding import current_rules, lsc
 from .params import P
 
 
-def ssd_pallas_sharded(x, dt, A, Bh, Ch, chunk, initial_state=None):
+def ssd_pallas_sharded(x, dt, A, Bm, Cm, chunk, initial_state=None):
     """SSD scan through the Pallas kernel, shard_mapped over the mesh.
 
-    x: (B,S,H,P); dt: (B,S,H); A: (H,); Bh/Ch: (B,S,H,N) head-broadcast.
+    x: (B,S,H,P); dt: (B,S,H); A: (H,); Bm/Cm: (B,S,G,N) at the group count.
     Batch rides ('pod','data'), heads ride 'model'; the sequence stays whole
-    per shard (the inter-chunk recurrence is sequential).  pallas_call has
-    no SPMD partitioning rule, so shard_map supplies the per-device view —
-    the production pattern for custom kernels.  Outside a rules context the
-    kernel runs unsharded (tests, single-host training).
+    per shard (the inter-chunk recurrence is sequential).  B and C are
+    replicated on 'model': with one group every shard's heads share it.
+    Where heads are split over devices and G > 1, a shard's heads need not
+    cover whole groups, so B and C are broadcast to the heads first and
+    shard with them.  pallas_call has no SPMD partitioning rule, so
+    shard_map supplies the per-device view — the production pattern for
+    custom kernels.  Outside a rules context the kernel runs unsharded
+    (tests, single-host training).
     """
     from ..kernels import ops as kops
 
     rules = current_rules()
     if rules is None:
-        return kops.ssd_scan(x, dt.astype(x.dtype), A, Bh, Ch, chunk=chunk,
+        return kops.ssd_scan(x, dt.astype(x.dtype), A, Bm, Cm, chunk=chunk,
                              initial_state=initial_state)
     mesh = rules.mesh
     x_spec = rules.act_spec(("batch", "seq", "ssm_heads", "head_dim"),
                             x.shape)
     dt_spec = rules.act_spec(("batch", "seq", "ssm_heads"), dt.shape)
     a_spec = rules.act_spec(("ssm_heads",), A.shape)
-    b_spec = rules.act_spec(("batch", "seq", "ssm_heads", "state"), Bh.shape)
+    heads_on = x_spec[2] or ()
+    head_shards = math.prod(mesh.shape[a] for a in (
+        (heads_on,) if isinstance(heads_on, str) else heads_on))
+    group_axis = None
+    if Bm.shape[2] > 1 and head_shards > 1:
+        hpg = x.shape[2] // Bm.shape[2]
+        Bm, Cm = (jnp.repeat(a, hpg, axis=2) for a in (Bm, Cm))
+        group_axis = "ssm_heads"
+    b_spec = rules.act_spec(("batch", "seq", group_axis, "state"), Bm.shape)
     st_spec = rules.act_spec(("batch", "ssm_heads", "head_dim", "state"),
                              (x.shape[0], x.shape[2], x.shape[3],
-                              Bh.shape[-1]))
+                              Bm.shape[-1]))
 
     if initial_state is None:
         def run(xl, dtl, al, bl, cl):
@@ -51,7 +64,7 @@ def ssd_pallas_sharded(x, dt, A, Bh, Ch, chunk, initial_state=None):
             run, mesh=mesh,
             in_specs=(x_spec, dt_spec, a_spec, b_spec, b_spec),
             out_specs=(x_spec, st_spec), check_vma=False,
-        )(x, dt.astype(x.dtype), A, Bh, Ch)
+        )(x, dt.astype(x.dtype), A, Bm, Cm)
 
     def run_init(xl, dtl, al, bl, cl, sl):
         return kops.ssd_scan(xl, dtl, al, bl, cl, chunk=chunk,
@@ -61,7 +74,7 @@ def ssd_pallas_sharded(x, dt, A, Bh, Ch, chunk, initial_state=None):
         run_init, mesh=mesh,
         in_specs=(x_spec, dt_spec, a_spec, b_spec, b_spec, st_spec),
         out_specs=(x_spec, st_spec), check_vma=False,
-    )(x, dt.astype(x.dtype), A, Bh, Ch, initial_state)
+    )(x, dt.astype(x.dtype), A, Bm, Cm, initial_state)
 
 
 def mamba_params(cfg: ModelConfig) -> dict:
@@ -255,11 +268,8 @@ def apply_mamba(p: dict, x_in: jax.Array, cfg: ModelConfig, *, mode: str,
     else:
         init = cache["state"] if cache else None
         if impl == "pallas":
-            hpg = nh // G
             with jax.named_scope("mixer.ssd_chunk"):
-                Bh = jnp.repeat(Bm, hpg, axis=2)          # (B,S,H,N)
-                Ch = jnp.repeat(Cm, hpg, axis=2)
-                y, final_state = ssd_pallas_sharded(xh, dt, A, Bh, Ch,
+                y, final_state = ssd_pallas_sharded(xh, dt, A, Bm, Cm,
                                                     s.chunk,
                                                     initial_state=init)
         else:

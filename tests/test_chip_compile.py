@@ -8,6 +8,8 @@ suite lives in this one file: the topology is described inside a fixture,
 never at import, so parallel test workers collect the same tests and only
 the worker given this file loads the TPU library.
 """
+import base64
+import json
 import re
 
 import jax
@@ -66,6 +68,47 @@ def test_ssd_forward_backward_compiles_for_v5e(one_chip):
     step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
     text = jax.jit(step).lower(*_ssd_args(one_chip)).compile().as_text()
     assert text.count("tpu_custom_call") == 2          # forward + backward
+
+
+def _mosaic_bodies(text):
+    """The serialized Mosaic module of each ``tpu_custom_call``."""
+    bodies = []
+    for cfg in re.findall(r'custom_call_target="tpu_custom_call".*?'
+                          r'backend_config=(\{.*)$', text, re.M):
+        obj, _ = json.JSONDecoder().raw_decode(cfg)
+        bodies.append(base64.b64decode(obj["custom_call_config"]["body"]))
+    return bodies
+
+
+def test_ssd_groups_reach_the_kernel_unbroadcast(one_chip, monkeypatch):
+    """mamba2-1.3b's one B/C group, through ops.ssd_scan forward and
+    backward: two kernel calls, no B/C (or dB/dC) array at the per-head
+    shape anywhere in the compiled step, and the kernels keep the body
+    names the benchmark finds them by."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    nc = 2
+    x, dt, Bm, Cm = _shapes(one_chip, jnp.float32, (1, nc * Q, H, P),
+                            (1, nc * Q, H), (1, nc * Q, 1, N),
+                            (1, nc * Q, 1, N))
+    A, = _shapes(one_chip, jnp.float32, (H,))
+
+    def loss(*args):
+        y, s = ops.ssd_scan(*args, chunk=Q)
+        return jnp.sum(jnp.sin(y)) + jnp.sum(s * s)
+
+    step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(step).lower(x, dt, A, Bm, Cm).compile().as_text()
+    assert text.count("tpu_custom_call") == 2          # forward + backward
+    per_head = re.compile(rf"f32\[[\d,]*\b({H},{Q}|{Q},{H}),{N}\]")
+    assert not per_head.findall(text)
+    bodies = _mosaic_bodies(text)
+    assert len(bodies) == 2
+    assert {n for b in bodies for n in (b"_ssd_chunk_kernel",
+                                        b"_ssd_chunk_bwd_kernel")
+            if re.search(rb"\b" + n + rb"\b", b)} == {
+        b"_ssd_chunk_kernel", b"_ssd_chunk_bwd_kernel"}
 
 
 def test_flash_attention_forward_compiles_for_v5e(one_chip):

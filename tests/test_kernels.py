@@ -1,4 +1,9 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -61,36 +66,50 @@ def test_flash_attention_ops_layout(key):
 
 
 # ------------------------------------------------------------------ SSD scan
+def _groups(G, H):
+    """The B/C group count of a case: a number, or "H" for one per head."""
+    return H if G == "H" else G
+
+
+def _heads(a, H):
+    """(B, L, G, N) at the group count -> (B, L, H, N), for the reference."""
+    return jnp.repeat(a, H // a.shape[2], axis=2)
+
+
+@pytest.mark.parametrize("G", [1, 2, "H"])
 @pytest.mark.parametrize("L,H,P,N,chunk", [
     (64, 2, 16, 16, 16),
     (128, 4, 32, 32, 32),
     (96, 2, 16, 8, 32),          # L not a multiple of chunk*2
 ])
-def test_ssd_scan_vs_sequential_ref(L, H, P, N, chunk, key):
+def test_ssd_scan_vs_sequential_ref(L, H, P, N, chunk, G, key):
+    G = _groups(G, H)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     B = 2
     x = jax.random.normal(k1, (B, L, H, P), jnp.float32)
     dt = jax.nn.softplus(jax.random.normal(k2, (B, L, H), jnp.float32))
     A = -jnp.exp(jax.random.normal(k3, (H,), jnp.float32) * 0.5)
-    Bm = jax.random.normal(k4, (B, L, H, N), jnp.float32) * 0.5
-    Cm = jax.random.normal(k1, (B, L, H, N), jnp.float32) * 0.5
+    Bm = jax.random.normal(k4, (B, L, G, N), jnp.float32) * 0.5
+    Cm = jax.random.normal(k1, (B, L, G, N), jnp.float32) * 0.5
     y, state = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
-    y_ref, state_ref = ref.ssd_ref(x, dt, A, Bm, Cm)
+    y_ref, state_ref = ref.ssd_ref(x, dt, A, _heads(Bm, H), _heads(Cm, H))
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(state), np.asarray(state_ref),
                                rtol=2e-3, atol=2e-3)
 
 
-def test_ssd_scan_initial_state(key):
+@pytest.mark.parametrize("G", [1, 2, "H"])
+def test_ssd_scan_initial_state(G, key):
     """Chunked scan over [x1; x2] == scan x1 then scan x2 from its state."""
-    B, L, H, P, N = 1, 64, 2, 16, 16
+    B, L, H, P, N = 1, 64, 4, 16, 16
+    G = _groups(G, H)
     k1, k2 = jax.random.split(key)
     x = jax.random.normal(k1, (B, L, H, P), jnp.float32)
     dt = jax.nn.softplus(jax.random.normal(k2, (B, L, H), jnp.float32))
     A = -jnp.ones((H,), jnp.float32)
-    Bm = jax.random.normal(k1, (B, L, H, N), jnp.float32) * 0.3
-    Cm = jax.random.normal(k2, (B, L, H, N), jnp.float32) * 0.3
+    Bm = jax.random.normal(k1, (B, L, G, N), jnp.float32) * 0.3
+    Cm = jax.random.normal(k2, (B, L, G, N), jnp.float32) * 0.3
     y_full, s_full = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=16)
     y1, s1 = ops.ssd_scan(x[:, :32], dt[:, :32], A, Bm[:, :32], Cm[:, :32],
                           chunk=16)
@@ -137,27 +156,31 @@ def test_stream_triad_kernel(n, block, key):
 
 
 # ------------------------------------------------ SSD backward (custom VJP)
-def test_ssd_kernel_gradients_match_reference(key):
+@pytest.mark.parametrize("G", [1, 2, "H"])
+def test_ssd_kernel_gradients_match_reference(G, key):
     """jax.grad through the Pallas fwd+bwd kernels == grad of the
-    sequential jnp recurrence."""
-    B, L, H, P, N, chunk = 2, 64, 2, 16, 16, 16
+    sequential jnp recurrence; dB and dC come out at the group shape, equal
+    to the reference's per-head gradients summed over each group's heads."""
+    B, L, H, P, N, chunk = 2, 64, 4, 16, 16, 16
+    G = _groups(G, H)
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     x = jax.random.normal(k1, (B, L, H, P), jnp.float32) * 0.5
     dt = jax.nn.softplus(jax.random.normal(k2, (B, L, H), jnp.float32))
     A = -jnp.exp(jax.random.normal(k3, (H,)) * 0.3)
-    Bm = jax.random.normal(k4, (B, L, H, N), jnp.float32) * 0.4
-    Cm = jax.random.normal(k5, (B, L, H, N), jnp.float32) * 0.4
+    Bm = jax.random.normal(k4, (B, L, G, N), jnp.float32) * 0.4
+    Cm = jax.random.normal(k5, (B, L, G, N), jnp.float32) * 0.4
 
     def loss_kernel(*args):
         y, s = ops.ssd_scan(*args, chunk=chunk)
         return jnp.sum(jnp.sin(y)) + jnp.sum(s * s)
 
-    def loss_ref(*args):
-        y, s = ref.ssd_ref(*args)
+    def loss_ref(x, dt, A, Bm, Cm):
+        y, s = ref.ssd_ref(x, dt, A, _heads(Bm, H), _heads(Cm, H))
         return jnp.sum(jnp.sin(y)) + jnp.sum(s * s)
 
     g_k = jax.grad(loss_kernel, argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
     g_r = jax.grad(loss_ref, argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+    assert g_k[3].shape == g_k[4].shape == (B, L, G, N)
     for a, b in zip(g_k, g_r):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -186,3 +209,58 @@ def test_apply_mamba_pallas_matches_jnp(key):
     for kk in g_j:
         np.testing.assert_allclose(np.asarray(g_p[kk]), np.asarray(g_j[kk]),
                                    rtol=5e-3, atol=5e-3, err_msg=kk)
+
+
+_SHARDED_CHILD = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax
+import jax.numpy as jnp
+from jax.sharding import AxisType
+from repro.models.ssm import ssd_pallas_sharded
+from repro.parallel.sharding import make_rules, use_rules
+
+mesh = jax.make_mesh((2, 2), ("data", "model"), (AxisType.Auto,) * 2)
+B, L, H, P, N = 2, 64, 4, 16, 16
+k = jax.random.split(jax.random.PRNGKey(0), 5)
+x = jax.random.normal(k[0], (B, L, H, P))
+dt = jax.nn.softplus(jax.random.normal(k[1], (B, L, H)))
+A = -jnp.exp(jax.random.normal(k[2], (H,)) * 0.3)
+
+
+def loss(*args):
+    y, s = ssd_pallas_sharded(*args, 16)
+    return jnp.sum(jnp.sin(y)) + jnp.sum(s * s)
+
+
+gaps = {}
+for G in (1, 2, H):
+    Bm = jax.random.normal(k[3], (B, L, G, N)) * 0.4
+    Cm = jax.random.normal(k[4], (B, L, G, N)) * 0.4
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 3, 4))
+    want = grad(x, dt, A, Bm, Cm)
+    with use_rules(make_rules(mesh)):
+        got = jax.jit(grad)(x, dt, A, Bm, Cm)
+    gaps[G] = max(float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                  for a, b in zip(jax.tree.leaves(got),
+                                  jax.tree.leaves(want)))
+print(json.dumps(gaps))
+"""
+
+
+def test_ssd_pallas_sharded_matches_unsharded():
+    """The shard_mapped SSD (batch on 'data', heads on 'model') equals the
+    unsharded kernel in value and gradient (largest gap over the largest
+    entry, leaf by leaf), with one group replicated to
+    every head shard, two groups (broadcast to heads first) and one group
+    per head.  Four CPU devices, so in a process of its own."""
+    env = dict(os.environ, PYTHONPATH=os.path.abspath("src"))
+    out = subprocess.run([sys.executable, "-c", _SHARDED_CHILD], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-2000:]
+    gaps = json.loads(out.stdout.strip().splitlines()[-1])
+    assert sorted(gaps) == ["1", "2", "4"]
+    assert all(g < 2e-5 for g in gaps.values()), gaps
