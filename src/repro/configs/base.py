@@ -10,6 +10,10 @@ from dataclasses import dataclass
 from typing import Optional
 
 
+# MLP kinds with a gate: act(x W_gate) * (x W_up), then down
+GLU_KINDS = ("swiglu", "geglu", "geglu_erf")
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
@@ -53,16 +57,26 @@ class ModelConfig:
     d_ff: int                          # FFN hidden (per-expert for MoE); 0 for attn-free
     vocab_size: int
     d_head: int = 0                    # 0 -> d_model // n_heads
-    mlp_kind: str = "swiglu"           # swiglu | geglu | sq_relu | gelu
+    mlp_kind: str = "swiglu"           # swiglu | geglu | geglu_erf | sq_relu | gelu
     norm_kind: str = "rmsnorm"         # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     qkv_bias: bool = False
     rope_fraction: float = 1.0         # fraction of head_dim carrying rotary (chatglm: 0.5)
     rope_theta: float = 10_000.0
+    rope_style: str = "interleaved"    # interleaved (x0,x1),(x2,x3).. | half (rotate-half)
+    attn_scale: float = 0.0            # softmax scale; 0 -> head_dim ** -0.5
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (zamba2): one weight-shared attention+MLP block applied every k layers
+    # hybrid (zamba2): one weight-shared attention+MLP block, invoked before
+    # the Mamba mixer of every k-th layer from layer k on (hybrid_layer_ids).
+    # Its attention reads ``attn_in`` features ([h; embedding]); each
+    # invocation has its own rank-``adapter_rank`` adapters (on q/k/v when
+    # ``attn_adapters``, always on the MLP's gate/up) and its own linear.
     shared_attn_every: int = 0
+    attn_in: int = 0                   # attention input width; 0 -> d_model
+    adapter_rank: int = 0
+    attn_adapters: bool = False
     # encoder-decoder (whisper): n_layers is the decoder depth
     n_encoder_layers: int = 0
     n_frames: int = 0                  # stub frontend: precomputed frame embeddings
@@ -80,6 +94,16 @@ class ModelConfig:
         if self.d_head:
             return self.d_head
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def attn_in_dim(self) -> int:
+        return self.attn_in or self.d_model
+
+    @property
+    def hybrid_layer_ids(self) -> tuple:
+        """Layers whose Mamba mixer takes the shared block's output."""
+        k = self.shared_attn_every
+        return tuple(range(k, self.n_layers, k)) if k else ()
 
     @property
     def padded_vocab(self) -> int:
@@ -114,16 +138,16 @@ class ModelConfig:
         total += norm_size                               # final norm
 
         def attn_params() -> int:
-            hd = self.head_dim
-            p = d * self.n_heads * hd                    # q
-            p += 2 * d * self.n_kv_heads * hd            # k, v
+            hd, a = self.head_dim, self.attn_in_dim
+            p = a * self.n_heads * hd                    # q
+            p += 2 * a * self.n_kv_heads * hd            # k, v
             p += self.n_heads * hd * d                   # o
             if self.qkv_bias:
                 p += (self.n_heads + 2 * self.n_kv_heads) * hd
             return p
 
         def mlp_params(d_ff: int) -> int:
-            if self.mlp_kind in ("swiglu", "geglu"):
+            if self.mlp_kind in GLU_KINDS:
                 return 3 * d * d_ff
             return 2 * d * d_ff
 
@@ -152,9 +176,17 @@ class ModelConfig:
             per_layer += conv_dim * s.d_conv + conv_dim
             per_layer += nh * 3 + di + di * d + d
             total += self.n_layers * per_layer
-            # one shared attn+MLP block
-            total += attn_params() + mlp_params(self.d_ff) + block_norms()
-            return total
+            # the one shared block: input norm over [h; e], attention, the
+            # pre-MLP norm and the MLP, counted once
+            a, hd, r = self.attn_in_dim, self.head_dim, self.adapter_rank
+            total += a + attn_params() + d + 3 * d * self.d_ff
+            # each invocation's adapters and linear
+            per_inv = d * r + r * 2 * self.d_ff          # gated MLP's gate/up
+            if self.attn_adapters:
+                per_inv += 3 * a * r + r * (self.n_heads
+                                            + 2 * self.n_kv_heads) * hd
+            per_inv += d * d
+            return total + len(self.hybrid_layer_ids) * per_inv
 
         per_layer = attn_params() + block_norms()
         if self.moe is not None:
@@ -181,7 +213,7 @@ class ModelConfig:
         d = self.d_model
 
         def mlp_params(d_ff: int) -> int:
-            if self.mlp_kind in ("swiglu", "geglu"):
+            if self.mlp_kind in GLU_KINDS:
                 return 3 * d * d_ff
             return 2 * d * d_ff
 

@@ -76,7 +76,20 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
             capacity_factor=cfg.moe.capacity_factor,
         )
     if cfg.shared_attn_every:
-        updates["shared_attn_every"] = 2
+        # five layers, hybrid at 2 and 4: two invocations of the shared
+        # block, Mamba layers before, between and after them
+        updates.update(n_layers=5, shared_attn_every=2,
+                       adapter_rank=min(cfg.adapter_rank, 8))
+    if cfg.attn_in:
+        # the shared block keeps its input at twice the width and its
+        # heads spanning that input, as the full config does
+        ratio = cfg.attn_in // cfg.d_model
+        updates.update(attn_in=ratio * 128,
+                       d_head=ratio * 128 // n_heads)
+    if cfg.attn_scale:
+        # keep the scale's relation to the head size: (hd / c) ** -0.5
+        hd = updates.get("d_head") or updates["d_model"] // max(n_heads, 1)
+        updates["attn_scale"] = cfg.attn_scale * (cfg.head_dim / hd) ** 0.5
     if cfg.n_encoder_layers:
         updates["n_encoder_layers"] = 2
     if cfg.n_frames:

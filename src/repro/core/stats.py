@@ -21,10 +21,16 @@ from collections import defaultdict
 from typing import Dict, Iterator, Optional, Tuple
 
 # The named scopes of the train step (models/lm.py, models/ssm.py,
-# kernels/ops.py, train/trainer.py).  An op in none of them is ``other``.
+# models/attention.py, kernels/ops.py, train/trainer.py).  An op in none of
+# them is ``other``.  The ``shared.*`` scopes are the hybrid's shared block
+# (zamba2): the [h; e] concatenation and its norm, q/k/v with adapters and
+# rope, the attention core, Wo, the MLP with its norm, and the invocation's
+# linear with the add into the Mamba input.
+SHARED_REGIONS = ("shared.in", "shared.qkv", "shared.attn", "shared.out",
+                  "shared.mlp", "shared.link")
 REGIONS = ("embed", "layers", "block_norm", "mixer.in_proj", "mixer.conv",
            "mixer.ssd_chunk", "mixer.ssd_state", "mixer.gate",
-           "mixer.out_proj", "head", "optimizer")
+           "mixer.out_proj", "head", "optimizer") + SHARED_REGIONS
 _REGION_SET = frozenset(REGIONS)
 _SPLIT = re.compile(r"[/;()]")
 

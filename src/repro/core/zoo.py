@@ -42,7 +42,7 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..configs import ARCHS, ZOO_SHAPES, reduced_config, zoo_phases_for
-from ..configs.base import ModelConfig, ShapeConfig
+from ..configs.base import GLU_KINDS, ModelConfig, ShapeConfig
 from .cluster import ClusterResult, ClusterWorkload, ShardDecision, \
     cluster_sweep
 from .cost import cost_program
@@ -761,10 +761,10 @@ def cluster_workload(arch: str, phase: str = "train",
     shape = shape or ZOO_SHAPES[phase]
     prog = trace_phase(arch, phase, shape, param_dtype, hlo_cache_dir)
     repeats = long_trace_repeats(arch, phase, decode_steps)
-    d, hd = full.d_model, full.head_dim
-    attn = d * full.n_heads * hd + 2 * d * full.n_kv_heads * hd \
+    d, hd, a = full.d_model, full.head_dim, full.attn_in_dim
+    attn = a * full.n_heads * hd + 2 * a * full.n_kv_heads * hd \
         + full.n_heads * hd * d
-    glu = 3 if full.mlp_kind in ("swiglu", "geglu") else 2
+    glu = 3 if full.mlp_kind in GLU_KINDS else 2
     active_k = full.moe.top_k if full.moe is not None else 1
     ffn = glu * d * full.d_ff * max(active_k, 1)
     frac_attn = attn / (attn + ffn) if full.n_heads else 0.0

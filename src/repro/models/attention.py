@@ -12,6 +12,7 @@ Two implementations share one math definition:
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Optional
 
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..parallel.sharding import current_rules, lsc
-from .layers import apply_rope
+from .layers import apply_rope, lora
 from .params import P
 
 
@@ -40,10 +41,11 @@ NEG_INF = -1e30
 
 def attn_params(cfg: ModelConfig) -> dict:
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    a = cfg.attn_in_dim
     out = {
-        "wq": P((d, h, hd), ("embed", "heads", "head_dim")),
-        "wk": P((d, kv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": P((d, kv, hd), ("embed", "kv_heads", "head_dim")),
+        "wq": P((a, h, hd), ("embed", "heads", "head_dim")),
+        "wk": P((a, kv, hd), ("embed", "kv_heads", "head_dim")),
+        "wv": P((a, kv, hd), ("embed", "kv_heads", "head_dim")),
         "wo": P((h, hd, d), ("heads", "head_dim", "embed")),
     }
     if cfg.qkv_bias:
@@ -64,9 +66,12 @@ def project_qkv(p: dict, x: jax.Array, cfg: ModelConfig):
     return q, k, v
 
 
-def project_kv(p: dict, x: jax.Array):
+def project_kv(p: dict, x: jax.Array, adapters: Optional[dict] = None):
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+    if adapters is not None:
+        k = k + lora(x, adapters["k"]["a"], adapters["k"]["b"])
+        v = v + lora(x, adapters["v"]["a"], adapters["v"]["b"])
     if "bk" in p:
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
@@ -85,16 +90,21 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool, q_offset: int = 0,
-                      block: int = 1024) -> jax.Array:
+                      block: int = 1024,
+                      scale: Optional[float] = None,
+                      remat: bool = False) -> jax.Array:
     """Online-softmax attention over KV blocks.
 
     q: (B, Sq, H, D); k, v: (B, Sk, KVH, D); H % KVH == 0.
-    Returns (B, Sq, H, D).  fp32 accumulation.
+    Returns (B, Sq, H, D).  fp32 accumulation.  ``scale`` multiplies the
+    scores (default ``D ** -0.5``).  With ``remat`` each block's scores are
+    recomputed in the backward pass instead of saved: the saved state is
+    then the running max, sum and output, not (Sq, block) per head.
     """
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qg = (q * scale).reshape(B, Sq, KVH, G, D)
 
     block = min(block, max(Sk, 1))
@@ -129,6 +139,8 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         acc_new = acc * alpha[..., None] + pv
         return (m_new, l_new, acc_new), None
 
+    if remat:
+        body = jax.checkpoint(body)
     m0 = jnp.full((B, KVH, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KVH, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, KVH, G, Sq, D), jnp.float32)
@@ -140,12 +152,13 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool, q_offset: int = 0) -> jax.Array:
+                    causal: bool, q_offset: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """Reference O(S^2)-memory attention (oracle for tests)."""
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
     G = H // KVH
-    qg = q.reshape(B, Sq, KVH, G, D) / math.sqrt(D)
+    qg = _scaled(q.reshape(B, Sq, KVH, G, D), scale)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                    preferred_element_type=jnp.float32)
     if causal:
@@ -155,6 +168,13 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
     return out.reshape(B, Sq, H, D).astype(q.dtype)
+
+
+def _scaled(q: jax.Array, scale: Optional[float]) -> jax.Array:
+    """q times the softmax scale (default: divided by sqrt(head_dim))."""
+    if scale is None:
+        return q / math.sqrt(q.shape[-1])
+    return q * scale
 
 
 def quantize_kv(x: jax.Array):
@@ -169,7 +189,8 @@ def quantize_kv(x: jax.Array):
 
 def decode_attention_q8(q: jax.Array, ck: jax.Array, cv: jax.Array,
                         k_scale: jax.Array, v_scale: jax.Array,
-                        length: jax.Array) -> jax.Array:
+                        length: jax.Array,
+                        scale: Optional[float] = None) -> jax.Array:
     """Decode attention over an int8-quantized cache (production serving
     feature; §Perf iteration E).  Exact math: per-(token, head) scales are
     applied to the *scores* and the *probabilities*, so the int8 tensors
@@ -181,7 +202,7 @@ def decode_attention_q8(q: jax.Array, ck: jax.Array, cv: jax.Array,
     G = H // KVH
     ck = lsc(ck, "batch", "kvseq", "kv_heads", "head_dim")
     cv = lsc(cv, "batch", "kvseq", "kv_heads", "head_dim")
-    qg = q.reshape(B, KVH, G, D) / math.sqrt(D)
+    qg = _scaled(q.reshape(B, KVH, G, D), scale)
     qg = lsc(qg, "batch", "kv_heads", "q_group", "head_dim")
     s = jnp.einsum("bhgd,bkhd->bhgk", qg.astype(jnp.float32),
                    ck.astype(jnp.float32),
@@ -198,7 +219,8 @@ def decode_attention_q8(q: jax.Array, ck: jax.Array, cv: jax.Array,
 
 
 def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-                     length: jax.Array) -> jax.Array:
+                     length: jax.Array,
+                     scale: Optional[float] = None) -> jax.Array:
     """q: (B, 1, H, D) against cache (B, Smax, KVH, D); positions >= length
     are masked.  fp32 softmax.
 
@@ -214,7 +236,7 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     G = H // KVH
     cache_k = lsc(cache_k, "batch", "kvseq", "kv_heads", "head_dim")
     cache_v = lsc(cache_v, "batch", "kvseq", "kv_heads", "head_dim")
-    qg = q.reshape(B, KVH, G, D) / math.sqrt(D)
+    qg = _scaled(q.reshape(B, KVH, G, D), scale)
     qg = lsc(qg, "batch", "kv_heads", "q_group", "head_dim")
     s = jnp.einsum("bhgd,bkhd->bhgk", qg, cache_k,
                    preferred_element_type=jnp.float32)
@@ -227,6 +249,13 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
+def _scope(scope: Optional[str], name: str):
+    """``jax.named_scope(f"{scope}.{name}")``, or nothing without a scope."""
+    if scope is None:
+        return contextlib.nullcontext()
+    return jax.named_scope(f"{scope}.{name}")
+
+
 def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
                     mode: str,
                     positions: Optional[jax.Array] = None,
@@ -235,27 +264,47 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
                     cross_x: Optional[jax.Array] = None,
                     causal: bool = True,
                     impl: str = "blocked",
-                    kv_block: int = 1024):
+                    kv_block: int = 1024,
+                    adapters: Optional[dict] = None,
+                    scope: Optional[str] = None,
+                    remat_blocks: bool = False):
     """Full attention sub-block: projections + rope + core + output proj.
 
     Returns (out, new_cache).  ``cache`` is a dict {k, v} (+ filled length
     tracked by the caller); for cross-attention the cache holds the encoder
-    K/V and is never updated after prefill.
+    K/V and is never updated after prefill.  ``adapters`` ({q, k, v}: {a,
+    b}) add low-rank terms to the projections, before rope.  With
+    ``scope``, the projections, the core and the output projection run
+    under the named scopes ``<scope>.qkv``, ``<scope>.attn``, ``<scope>.out``.
+    ``remat_blocks`` recomputes the blocked core's scores in the backward
+    pass (``blocked_attention``'s ``remat``).
     """
     B, S, _ = x.shape
     is_cross = cross_x is not None or (cache is not None and cache.get("cross", False))
+    scale = cfg.attn_scale or None
 
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    if "bq" in p:
-        q = q + p["bq"].astype(q.dtype)
-    seq_ax = _attn_seq_axis(q.shape)
-    q = lsc(q, "batch", seq_ax, "heads", "head_dim")
+    def rope(t):
+        return apply_rope(t, positions, cfg.rope_fraction, cfg.rope_theta,
+                          cfg.rope_style)
 
-    if positions is None:
-        positions = jnp.arange(S)[None, :]
+    with _scope(scope, "qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        if adapters is not None:
+            q = q + lora(x, adapters["q"]["a"], adapters["q"]["b"])
+        if "bq" in p:
+            q = q + p["bq"].astype(q.dtype)
+        seq_ax = _attn_seq_axis(q.shape)
+        q = lsc(q, "batch", seq_ax, "heads", "head_dim")
 
-    if not is_cross and cfg.rope_fraction > 0:
-        q = apply_rope(q, positions, cfg.rope_fraction, cfg.rope_theta)
+        if positions is None:
+            positions = jnp.arange(S)[None, :]
+
+        if not is_cross and cfg.rope_fraction > 0:
+            q = rope(q)
+        if not is_cross:
+            k, v = project_kv(p, x, adapters)
+            if cfg.rope_fraction > 0:
+                k = rope(k)
 
     new_cache = cache
     if is_cross:
@@ -265,51 +314,55 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
         else:
             k, v = cache["k"], cache["v"]
         if mode == "decode":
-            out = decode_attention(q, k, v, jnp.asarray(k.shape[1]))
+            out = decode_attention(q, k, v, jnp.asarray(k.shape[1]), scale)
         else:
-            out = (blocked_attention(q, k, v, causal=False, block=kv_block)
+            out = (blocked_attention(q, k, v, causal=False, block=kv_block,
+                                     scale=scale)
                    if impl != "naive"
-                   else naive_attention(q, k, v, causal=False))
+                   else naive_attention(q, k, v, causal=False, scale=scale))
     elif mode == "decode":
-        k, v = project_kv(p, x)
-        if cfg.rope_fraction > 0:
-            k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
-        if "k_scale" in cache:                     # int8-quantized cache
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
+        with _scope(scope, "attn"):
             dus = jax.lax.dynamic_update_slice_in_dim
-            ck = dus(cache["k"], kq, cache_pos, axis=1)
-            cv = dus(cache["v"], vq, cache_pos, axis=1)
-            cks = dus(cache["k_scale"], ks.astype(cache["k_scale"].dtype),
-                      cache_pos, axis=1)
-            cvs = dus(cache["v_scale"], vs.astype(cache["v_scale"].dtype),
-                      cache_pos, axis=1)
-            ck = lsc(ck, "batch", "kvseq", "kv_heads", "head_dim")
-            cv = lsc(cv, "batch", "kvseq", "kv_heads", "head_dim")
-            new_cache = dict(cache, k=ck, v=cv, k_scale=cks, v_scale=cvs)
-            out = decode_attention_q8(q, ck, cv, cks, cvs, cache_pos + 1)
-        else:
-            dus = jax.lax.dynamic_update_slice_in_dim
-            ck = dus(cache["k"], k.astype(cache["k"].dtype), cache_pos, axis=1)
-            cv = dus(cache["v"], v.astype(cache["v"].dtype), cache_pos, axis=1)
-            ck = lsc(ck, "batch", "kvseq", "kv_heads", "head_dim")
-            cv = lsc(cv, "batch", "kvseq", "kv_heads", "head_dim")
-            new_cache = dict(cache, k=ck, v=cv)
-            out = decode_attention(q, ck, cv, cache_pos + 1)
+            if "k_scale" in cache:                     # int8-quantized cache
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
+                ck = dus(cache["k"], kq, cache_pos, axis=1)
+                cv = dus(cache["v"], vq, cache_pos, axis=1)
+                cks = dus(cache["k_scale"], ks.astype(cache["k_scale"].dtype),
+                          cache_pos, axis=1)
+                cvs = dus(cache["v_scale"], vs.astype(cache["v_scale"].dtype),
+                          cache_pos, axis=1)
+                ck = lsc(ck, "batch", "kvseq", "kv_heads", "head_dim")
+                cv = lsc(cv, "batch", "kvseq", "kv_heads", "head_dim")
+                new_cache = dict(cache, k=ck, v=cv, k_scale=cks, v_scale=cvs)
+                out = decode_attention_q8(q, ck, cv, cks, cvs, cache_pos + 1,
+                                          scale)
+            else:
+                ck = dus(cache["k"], k.astype(cache["k"].dtype), cache_pos,
+                         axis=1)
+                cv = dus(cache["v"], v.astype(cache["v"].dtype), cache_pos,
+                         axis=1)
+                ck = lsc(ck, "batch", "kvseq", "kv_heads", "head_dim")
+                cv = lsc(cv, "batch", "kvseq", "kv_heads", "head_dim")
+                new_cache = dict(cache, k=ck, v=cv)
+                out = decode_attention(q, ck, cv, cache_pos + 1, scale)
     else:  # train / prefill self-attention
-        k, v = project_kv(p, x)
-        if cfg.rope_fraction > 0:
-            k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
         if mode == "prefill":
             new_cache = {"k": k, "v": v, "cross": False}
-        if impl == "naive":
-            out = naive_attention(q, k, v, causal=causal)
-        elif impl == "flash":
-            from ..kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=causal)
-        else:
-            out = blocked_attention(q, k, v, causal=causal, block=kv_block)
+        with _scope(scope, "attn"):
+            if impl == "naive":
+                out = naive_attention(q, k, v, causal=causal, scale=scale)
+            elif impl == "flash":
+                from ..kernels import ops as kops
+                if scale is not None:         # the kernel scales by D ** -0.5
+                    q = q * (scale * math.sqrt(q.shape[-1]))
+                out = kops.flash_attention(q, k, v, causal=causal)
+            else:
+                out = blocked_attention(q, k, v, causal=causal,
+                                        block=kv_block, scale=scale,
+                                        remat=remat_blocks)
 
-    out = lsc(out, "batch", seq_ax, "heads", "head_dim")
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return lsc(y, "batch", "rseq", "embed"), new_cache
+    with _scope(scope, "out"):
+        out = lsc(out, "batch", seq_ax, "heads", "head_dim")
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+        return lsc(y, "batch", "rseq", "embed"), new_cache

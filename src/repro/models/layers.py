@@ -7,7 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..configs.base import ModelConfig
+from ..configs.base import GLU_KINDS, ModelConfig
 from ..parallel.sharding import lsc
 from .params import P
 
@@ -53,8 +53,11 @@ def rope_freqs(head_dim: int, fraction: float, theta: float) -> Optional[jax.Arr
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, fraction: float,
-               theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+               theta: float, style: str = "interleaved") -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+
+    ``style`` pairs the rotated features: ``interleaved`` rotates (x0, x1),
+    (x2, x3), ...; ``half`` rotates x_i with x_{i + rot/2} (rotate-half)."""
     hd = x.shape[-1]
     inv = rope_freqs(hd, fraction, theta)
     if inv is None:
@@ -64,6 +67,12 @@ def apply_rope(x: jax.Array, positions: jax.Array, fraction: float,
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., seq, rot/2)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if style == "half":
+        x1 = xr[..., :rot // 2].astype(jnp.float32)
+        x2 = xr[..., rot // 2:].astype(jnp.float32)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1).astype(x.dtype)
+        return jnp.concatenate([out, xp], axis=-1)
     x1 = xr[..., 0::2].astype(jnp.float32)
     x2 = xr[..., 1::2].astype(jnp.float32)
     o1 = x1 * cos - x2 * sin
@@ -75,7 +84,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, fraction: float,
 # ---------------------------------------------------------------------- MLP
 def mlp_params(cfg: ModelConfig) -> dict:
     d, f = cfg.d_model, cfg.d_ff
-    if cfg.mlp_kind in ("swiglu", "geglu"):
+    if cfg.mlp_kind in GLU_KINDS:
         return {
             "wi_gate": P((d, f), ("embed", "mlp")),
             "wi_up": P((d, f), ("embed", "mlp")),
@@ -84,12 +93,17 @@ def mlp_params(cfg: ModelConfig) -> dict:
     return {"wi": P((d, f), ("embed", "mlp")), "wo": P((f, d), ("mlp", "embed"))}
 
 
+def _gate_act(kind: str, g: jax.Array) -> jax.Array:
+    if kind == "swiglu":
+        return jax.nn.silu(g)
+    return jax.nn.gelu(g, approximate=kind != "geglu_erf")
+
+
 def apply_mlp(p: dict, x: jax.Array, kind: str) -> jax.Array:
-    if kind in ("swiglu", "geglu"):
+    if kind in GLU_KINDS:
         g = jnp.einsum("...d,df->...f", x, p["wi_gate"])
         u = jnp.einsum("...d,df->...f", x, p["wi_up"])
-        act = jax.nn.silu(g) if kind == "swiglu" else jax.nn.gelu(g)
-        h = act * u
+        h = _gate_act(kind, g) * u
     else:
         h = jnp.einsum("...d,df->...f", x, p["wi"])
         if kind == "sq_relu":
@@ -98,6 +112,29 @@ def apply_mlp(p: dict, x: jax.Array, kind: str) -> jax.Array:
             h = jax.nn.gelu(h)
     h = lsc(h, "batch", "rseq", "mlp")
     return jnp.einsum("...f,fd->...d", h, p["wo"])
+
+
+def shared_mlp_params(cfg: ModelConfig) -> dict:
+    """The shared block's gated MLP (zamba2): one gate/up weight (d, 2, f),
+    so that each invocation's adapter adds to both halves."""
+    d, f = cfg.d_model, cfg.d_ff
+    return {"gate_up": P((d, 2, f), ("embed", None, "mlp")),
+            "down": P((f, d), ("mlp", "embed"))}
+
+
+def lora(x: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
+    """The adapter's term ``(x A) B``; B's trailing axes give its output."""
+    return jnp.tensordot(jnp.einsum("...i,ir->...r", x, a), b, axes=1)
+
+
+def apply_shared_mlp(p: dict, adapter: dict, x: jax.Array,
+                     kind: str) -> jax.Array:
+    """``down(act(g) * u)`` with ``[g, u] = x W + (x A) B``."""
+    gu = jnp.einsum("...d,dnf->...nf", x, p["gate_up"]) \
+        + lora(x, adapter["a"], adapter["b"])
+    h = _gate_act(kind, gu[..., 0, :]) * gu[..., 1, :]
+    h = lsc(h, "batch", "rseq", "mlp")
+    return jnp.einsum("...f,fd->...d", h, p["down"])
 
 
 # ----------------------------------------------------------------- embedding

@@ -8,9 +8,11 @@ the three entry points every (arch x shape) cell lowers:
 * ``decode_fn(params, cache, batch)``   — decode_32k / long_500k (1 new token)
 
 Homogeneous stacks (dense / moe / ssm / whisper enc+dec) are ``lax.scan``-ed
-over stacked layer parameters (small HLO, fast SPMD partitioning); the zamba2
-hybrid uses a python loop (38 layers, heterogeneous: shared attention block
-every 6th layer).
+over stacked layer parameters (small HLO, fast SPMD partitioning).  The
+zamba2 hybrid stacks its Mamba-2 layers the same way and scans each run of
+them between invocations of its one shared transformer block; the block
+reads ``[h; e]`` (``e`` the embedding output) and its output, through the
+invocation's own linear, joins that layer's Mamba input (``_hybrid_stack``).
 """
 from __future__ import annotations
 
@@ -27,12 +29,14 @@ from .attention import attn_params, attention_block
 from .layers import (
     apply_mlp,
     apply_norm,
+    apply_shared_mlp,
     embed_params,
     embed_tokens,
     logits_from_hidden,
     mlp_params,
     next_token_loss,
     norm_params,
+    shared_mlp_params,
 )
 from .moe import apply_moe, moe_params
 from .params import P
@@ -56,6 +60,15 @@ def constrain_params(param_tree, spec_tree):
     EXPERIMENTS.md §Perf iteration 1)."""
     return jax.tree.map(lambda a, p: lsc_param(a, *p.axes), param_tree,
                         spec_tree)
+
+
+def split_layers(tree, cuts):
+    """A stacked tree cut along its layer axis before each index of
+    ``cuts``: a list of trees, in order."""
+    leaves, treedef = jax.tree.flatten(tree)
+    parts = [jnp.split(a, cuts) for a in leaves]
+    return [jax.tree.unflatten(treedef, [p[i] for p in parts])
+            for i in range(len(cuts) + 1)]
 
 
 def _sinusoidal(positions: jax.Array, d: int, dtype) -> jax.Array:
@@ -97,6 +110,37 @@ class LM:
         out["xattn"] = attn_params(self.cfg)
         return out
 
+    def _shared_specs(self) -> dict:
+        """The one shared block (zamba2): the norm over [h; e], attention
+        from that width, the pre-MLP norm and the gated MLP."""
+        cfg = self.cfg
+        return {"ln_in": {"scale": P((cfg.attn_in_dim,), ("embed",), "ones")},
+                "attn": attn_params(cfg), "ln_ff": norm_params(cfg),
+                "mlp": shared_mlp_params(cfg)}
+
+    def _invocation_specs(self) -> dict:
+        """One invocation's own weights: rank-r adapters (x A) B on q, k, v
+        (if ``attn_adapters``) and on the MLP's gate/up, and the linear that
+        maps the block's output into the Mamba layer's input."""
+        cfg = self.cfg
+        r, hd, d = cfg.adapter_rank, cfg.head_dim, cfg.d_model
+
+        def adapter(n_in, out, axes):
+            return {"a": P((n_in, r), ("embed", None)),
+                    "b": P((r,) + out, (None,) + axes)}
+
+        out = {}
+        if cfg.attn_adapters:
+            a = cfg.attn_in_dim
+            out["q"] = adapter(a, (cfg.n_heads, hd), ("heads", "head_dim"))
+            out["k"] = adapter(a, (cfg.n_kv_heads, hd),
+                               ("kv_heads", "head_dim"))
+            out["v"] = adapter(a, (cfg.n_kv_heads, hd),
+                               ("kv_heads", "head_dim"))
+        out["gate_up"] = adapter(d, (2, cfg.d_ff), (None, "mlp"))
+        out["linear"] = P((d, d), ("embed", None))
+        return out
+
     def param_specs(self) -> dict:
         cfg = self.cfg
         specs: dict[str, Any] = {"embed": embed_params(cfg),
@@ -107,10 +151,9 @@ class LM:
         elif cfg.family == "hybrid":
             layer = {"ln": norm_params(cfg), "mamba": mamba_params(cfg)}
             specs["layers"] = stack_specs(layer, cfg.n_layers)
-            specs["shared_attn"] = {
-                "ln1": norm_params(cfg), "attn": attn_params(cfg),
-                "ln2": norm_params(cfg), "mlp": mlp_params(cfg),
-            }
+            specs["shared"] = self._shared_specs()
+            specs["invocations"] = stack_specs(self._invocation_specs(),
+                                               self.n_shared_invocations())
         elif cfg.family == "audio":
             specs["layers"] = stack_specs(self._decoder_xattn_layer_specs(),
                                           cfg.n_layers)
@@ -133,9 +176,7 @@ class LM:
     # --------------------------------------------------------------- caches
     def n_shared_invocations(self) -> int:
         cfg = self.cfg
-        if cfg.family != "hybrid":
-            return 0
-        return len(range(0, cfg.n_layers, cfg.shared_attn_every))
+        return len(cfg.hybrid_layer_ids) if cfg.family == "hybrid" else 0
 
     def cache_specs(self, batch: int, max_seq: int, dtype=jnp.bfloat16) -> dict:
         """Cache tree as P-leaves (shape + logical axes) for dry-run specs."""
@@ -314,91 +355,112 @@ class LM:
                                         (params["layers"], cache))
         return x, aux, caches
 
-    def _ssm_stack(self, params, x, mode, cache, pos):
+    def _mamba_body(self, mode):
+        """The scan body of a Mamba-2 layer: ``h + Mamba(norm(u))``, with
+        ``u = h`` unless the caller gives the layer another input."""
         cfg = self.cfg
         layer_specs = {"ln": norm_params(cfg), "mamba": mamba_params(cfg)}
 
-        def body(h, scanned):
+        def body(h, scanned, u=None):
             lp, lc = scanned
             lp = constrain_params(lp, layer_specs)
             with jax.named_scope("block_norm"):
-                a_in = apply_norm(lp["ln"], h)
+                a_in = apply_norm(lp["ln"], h if u is None else u,
+                                  cfg.norm_eps)
             a, new_lc = apply_mamba(lp["mamba"], a_in, cfg, mode=mode,
                                     cache=lc, impl=self.ssd_impl)
             with jax.named_scope("block_norm"):
                 h = lsc(h + a, "batch", "rseq", "embed")
             return h, new_lc
 
+        return body
+
+    def _ssm_stack(self, params, x, mode, cache, pos):
+        cfg = self.cfg
+        body = self._mamba_body(mode)
         if cfg.remat == "full" and mode == "train":
             body = jax.checkpoint(body)
         with jax.named_scope("layers"):
             x, caches = jax.lax.scan(body, x, (params["layers"], cache))
         return x, 0.0, caches
 
-    def _hybrid_stack(self, params, x, mode, cache, pos):
-        """zamba2: python loop; shared attn block every k layers."""
+    def _shared_block(self, sp, ip, h, e, mode, positions, kv_cache, pos):
+        """zamba2's shared transformer block on ``[h; e]`` with invocation
+        weights ``ip``.  Returns (its output, the attention's new K/V)."""
         cfg = self.cfg
-        every = cfg.shared_attn_every
-        sp = constrain_params(
-            params["shared_attn"],
-            {"ln1": norm_params(cfg), "attn": attn_params(cfg),
-             "ln2": norm_params(cfg), "mlp": mlp_params(cfg)})
+        with jax.named_scope("shared.in"):
+            u = apply_norm(sp["ln_in"], jnp.concatenate([h, e], axis=-1),
+                           cfg.norm_eps)
+        a, kv = attention_block(
+            sp["attn"], u, cfg, mode=mode, positions=positions,
+            cache=kv_cache, cache_pos=pos, impl=self.attn_impl,
+            kv_block=self.kv_block,
+            adapters=ip if cfg.attn_adapters else None, scope="shared",
+            remat_blocks=mode == "train" and cfg.remat == "full")
+        with jax.named_scope("shared.mlp"):
+            n = apply_norm(sp["ln_ff"], a, cfg.norm_eps)
+            t = apply_shared_mlp(sp["mlp"], ip["gate_up"], n, cfg.mlp_kind)
+        return t, kv
+
+    def _hybrid_stack(self, params, x, mode, cache, pos):
+        """zamba2: the Mamba-2 layers in runs, each run one ``lax.scan`` of
+        ``_mamba_body``; hybrid layer i (``cfg.hybrid_layer_ids``, invocation
+        j) computes ``h + Mamba_i(norm(h + shared_j(h, e) W_j))``."""
+        cfg = self.cfg
+        ids = cfg.hybrid_layer_ids
+        e = x
         B, S = x.shape[:2]
         positions = (jnp.arange(S)[None, :] if pos is None
                      else pos + jnp.zeros((B, 1), jnp.int32))
-        new_cache = {"mamba": {k: [] for k in
-                               ("conv_x", "conv_B", "conv_C", "state")},
-                     "shared_k": [], "shared_v": []} if mode != "train" else None
+        remat = cfg.remat == "full" and mode == "train"
+        body = self._mamba_body(mode)
+        scan_body = jax.checkpoint(body) if remat else body
+        sp = constrain_params(params["shared"], self._shared_specs())
 
-        def layer(h, lp, lc, inv_cache, use_attn):
-            if use_attn:
-                a_in = apply_norm(sp["ln1"], h)
-                a, kvout = attention_block(
-                    sp["attn"], a_in, cfg, mode=mode, positions=positions,
-                    cache=inv_cache, cache_pos=pos, impl=self.attn_impl,
-                    kv_block=self.kv_block)
-                h = h + a
-                f_in = apply_norm(sp["ln2"], h)
-                h = h + apply_mlp(sp["mlp"], f_in, cfg.mlp_kind)
-            else:
-                kvout = None
-            m_in = apply_norm(lp["ln"], h)
-            m, new_lc = apply_mamba(lp["mamba"], m_in, cfg, mode=mode,
-                                    cache=lc, impl=self.ssd_impl)
-            return h + m, new_lc, kvout
+        def hybrid(h, e, lp, lc, sp, ip, kv_cache):
+            t, kv = self._shared_block(sp, ip, h, e, mode, positions,
+                                       kv_cache, pos)
+            with jax.named_scope("shared.link"):
+                u = h + jnp.einsum("bsd,de->bse", t, ip["linear"])
+            h, new_lc = body(h, (lp, lc), u)
+            return h, new_lc, kv
 
-        if cfg.remat == "full" and mode == "train":
-            layer = jax.checkpoint(layer, static_argnums=(4,))
+        if remat:
+            hybrid = jax.checkpoint(hybrid)
 
-        inv = 0
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            use_attn = (i % every == 0)
-            lc = None
-            inv_cache = None
+        # runs of plain layers and single hybrid layers, in order
+        cuts = sorted({c for i in ids for c in (i, i + 1)} - {cfg.n_layers})
+        n = len(cuts) + 1
+        with jax.named_scope("layers"):
+            pieces = split_layers(params["layers"], cuts)
+            lcs = (split_layers(cache["mamba"], cuts) if cache is not None
+                   else [None] * n)
+        new_lcs, new_k, new_v = [], [], []
+        for start, lp, lc in zip([0] + cuts, pieces, lcs):
+            if start not in ids:
+                with jax.named_scope("layers"):
+                    x, nlc = jax.lax.scan(scan_body, x, (lp, lc))
+                new_lcs.append(nlc)
+                continue
+            j = ids.index(start)
+            with jax.named_scope("layers"):
+                ip = jax.tree.map(lambda a: a[j], params["invocations"])
+                lp, lc = jax.tree.map(lambda a: a[0], (lp, lc))
+            kv_cache = None
             if cache is not None:
-                lc = jax.tree.map(lambda a, i=i: a[i], cache["mamba"])
-                if use_attn:
-                    inv_cache = {"k": cache["shared_k"][inv],
-                                 "v": cache["shared_v"][inv], "cross": False}
-            elif mode == "prefill":
-                lc = None
-            x, new_lc, kvout = layer(x, lp, lc, inv_cache, use_attn)
-            if new_cache is not None:
-                if new_lc is not None:
-                    for k in new_cache["mamba"]:
-                        new_cache["mamba"][k].append(new_lc[k])
-                if use_attn and kvout is not None:
-                    new_cache["shared_k"].append(kvout["k"])
-                    new_cache["shared_v"].append(kvout["v"])
-            if use_attn:
-                inv += 1
-
-        if new_cache is not None:
-            new_cache["mamba"] = {k: jnp.stack(v) for k, v in
-                                  new_cache["mamba"].items()}
-            new_cache["shared_k"] = jnp.stack(new_cache["shared_k"])
-            new_cache["shared_v"] = jnp.stack(new_cache["shared_v"])
+                kv_cache = {"k": cache["shared_k"][j],
+                            "v": cache["shared_v"][j], "cross": False}
+            x, nlc, kv = hybrid(x, e, lp, lc, sp, ip, kv_cache)
+            if mode != "train":
+                new_lcs.append(jax.tree.map(lambda a: a[None], nlc))
+                new_k.append(kv["k"])
+                new_v.append(kv["v"])
+        if mode == "train":
+            return x, 0.0, None
+        new_cache = {"mamba": jax.tree.map(lambda *a: jnp.concatenate(a),
+                                           *new_lcs),
+                     "shared_k": jnp.stack(new_k),
+                     "shared_v": jnp.stack(new_v)}
         return x, 0.0, new_cache
 
     def forward(self, params, batch: dict, mode: str, cache=None,
@@ -418,7 +480,7 @@ class LM:
             x, aux, caches = self._dense_stack(params, x, mode, cache, pos,
                                                cross_x)
         with jax.named_scope("head"):
-            x = apply_norm(params["final_norm"], x)
+            x = apply_norm(params["final_norm"], x, cfg.norm_eps)
             logits = logits_from_hidden(params["embed"], x, cfg)
         return logits, aux, caches
 

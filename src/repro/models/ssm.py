@@ -282,7 +282,7 @@ def apply_mamba(p: dict, x_in: jax.Array, cfg: ModelConfig, *, mode: str,
     with jax.named_scope("mixer.gate"):
         y = y + xh * p["D"].astype(y.dtype)[:, None]
         y = y.reshape(Bsz, S, di)
-        y = rms_norm_gated(y, p["norm"], z)
+        y = rms_norm_gated(y, p["norm"], z, cfg.norm_eps)
     with jax.named_scope("mixer.out_proj"):
         out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     return lsc(out, "batch", "rseq", "embed"), new_cache

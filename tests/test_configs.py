@@ -10,7 +10,7 @@ from repro.models.lm import build_model
 # configs are ambiguous (padded vocab, biases, exact d_ff).
 PUBLISHED = {
     "paligemma-3b": (2.9e9, 0.25),       # 3B incl. vision tower (ours: stub)
-    "zamba2-1.2b": (1.2e9, 0.25),
+    "zamba2-1.2b": (1.2e9, 0.02),        # 1.205B: see the test below
     "nemotron-4-340b": (340e9, 0.10),
     "qwen1.5-32b": (32e9, 0.10),
     "qwen1.5-110b": (110e9, 0.10),
@@ -48,6 +48,24 @@ def test_active_params(name):
         assert active == cfg.param_count()
     else:
         assert active < cfg.param_count()
+
+
+def test_zamba2_param_count_by_part():
+    """Zamba2-1.2B as built: 38 Mamba-2 layers, the shared block counted
+    once, and each of its 6 invocations' adapters and linear."""
+    cfg = ARCHS["zamba2-1.2b"]
+    mamba = (2048 * (2 * 4096 + 2 * 64 + 64)     # in projections
+             + (4096 + 2 * 64) * 5                 # conv weight and bias
+             + 3 * 64 + 4096 + 4096 * 2048 + 2048)  # dt, A, D; norms; out
+    shared = (4096 + 3 * 4096 * 4096 + 4096 * 2048  # [h; e] norm, q/k/v, o
+              + 2048 + 2048 * 2 * 8192 + 8192 * 2048)  # pre-MLP norm, MLP
+    per_invocation = (3 * (4096 * 128 + 128 * 4096)  # q/k/v adapters
+                      + 2048 * 128 + 128 * 2 * 8192  # gate/up adapter
+                      + 2048 * 2048)                 # linear
+    embed = 32000 * 2048 + 2048                      # tied; final norm
+    assert cfg.hybrid_layer_ids == (6, 12, 18, 24, 30, 36)
+    assert cfg.param_count() == embed + 38 * mamba + shared \
+        + 6 * per_invocation == 1_205_078_912
 
 
 def test_moe_actives_roughly_published():

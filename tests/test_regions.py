@@ -13,7 +13,7 @@ from repro.configs import ARCHS, RunConfig, ShapeConfig, reduced_config
 from repro.core.hlo import op_names, parse_program
 from repro.core.hwspec import TPU_V5E
 from repro.core.simulate import simulate
-from repro.core.stats import REGIONS, Stats, region_of
+from repro.core.stats import REGIONS, SHARED_REGIONS, Stats, region_of
 from repro.launch.train import build_training
 from repro.models.lm import build_model
 
@@ -95,7 +95,42 @@ def test_every_dot_and_the_ssd_kernel_fall_in_a_region(step_text):
     seen = {region_of(o) for o in names.values()}
     assert {p for r, p in seen if r} == {"forward", "recompute", "backward",
                                          "optimizer"}
+    assert {r for r, _ in seen if r} == set(REGIONS) - set(SHARED_REGIONS)
+
+
+def test_every_instruction_of_the_hybrid_step_falls_in_a_region():
+    """zamba2 at a reduced width (five layers, the shared block invoked at
+    layers 2 and 4), its train step compiled for the CPU: every instruction
+    the device runs as a unit (outside fusion bodies and reducers) maps to
+    a region, and the shared block's regions appear in the forward, the
+    recompute and the backward."""
+    mc = dataclasses.replace(reduced_config(ARCHS["zamba2-1.2b"]),
+                             remat="full")
+    model = build_model(mc, ssd_impl="pallas", kv_block=32)
+    run = RunConfig(model=mc, shape=ShapeConfig("t", 64, 2, "train"),
+                    param_dtype="float32", compute_dtype="float32")
+    jitted, init, _ = build_training(model, run)
+    params, opt = init(0)
+    batch = {"tokens": jnp.zeros((2, 64), jnp.int32)}
+    text = jitted.lower(params, opt, batch).compile().as_text()
+    names = op_names(text)
+    called = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    comp, units = None, []
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{$", line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \S+ (\S+?)\(", line)
+        if m and comp not in called and m.group(2) != "parameter":
+            units.append(m.group(1))
+    assert len(units) > 1000
+    assert [n for n in units if not region_of(names.get(n, ""))[0]] == []
+    seen = {region_of(o) for o in names.values()}
     assert {r for r, _ in seen if r} == set(REGIONS)
+    for r in SHARED_REGIONS:
+        assert {p for q, p in seen if q == r} == {"forward", "recompute",
+                                                 "backward"}, r
 
 
 def _instructions(text):
