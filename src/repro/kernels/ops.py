@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..parallel.sharding import current_rules
 from . import flash_attention as _fa
 from . import ssd_scan as _ssd
 from . import stream as _stream
@@ -22,18 +23,25 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("causal", "scale"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128) -> jax.Array:
-    """(B, S, H, D)-layout flash attention (matches models.attention)."""
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    out = _fa.flash_attention_bhsd(qt, kt, vt, causal=causal,
-                                   block_q=block_q, block_k=block_k,
-                                   interpret=_interpret())
-    return jnp.transpose(out, (0, 2, 1, 3))
+                    causal: bool = True,
+                    scale: Optional[float] = None) -> jax.Array:
+    """(B, S, H, D)-layout flash attention (matches models.attention), with
+    its Pallas backward."""
+    return _fa.flash_attention(q, k, v, causal=causal, scale=scale,
+                               interpret=_interpret())
+
+
+def flash_fits(q_shape, k_shape, *, causal: bool) -> bool:
+    """Whether attention the models would run blocked runs as the flash
+    kernel instead: causal self-attention (q and k of one length; the
+    models' self-attention has no query offset) with whole 128-lane heads,
+    on a TPU, outside a mesh's sharding rules (``pallas_call`` has no
+    partitioning rule, and the kernel has no ``shard_map`` wrapper yet).
+    Elsewhere the jnp blocked core runs."""
+    return (not _interpret() and current_rules() is None and causal
+            and q_shape[1] == k_shape[1] and q_shape[-1] % 128 == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -11,15 +11,18 @@ from .stream import EXPRS, _DTYPES
 
 
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        causal: bool = True) -> jax.Array:
-    """Naive O(S^2) attention.  q: (B,H,Sq,D); k,v: (B,KVH,Sk,D)."""
+                        causal: bool = True,
+                        scale: Optional[float] = None) -> jax.Array:
+    """Naive O(S^2) attention.  q: (B,H,Sq,D); k,v: (B,KVH,Sk,D); the
+    scores scaled by ``scale`` (default ``D ** -0.5``)."""
     B, H, Sq, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]
     G = H // KVH
     kr = jnp.repeat(k, G, axis=1)
     vr = jnp.repeat(v, G, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   kr.astype(jnp.float32)) / math.sqrt(D)
+                   kr.astype(jnp.float32))
+    s = s / math.sqrt(D) if scale is None else s * scale
     if causal:
         mask = jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]
         s = jnp.where(mask, s, -1e30)

@@ -6,8 +6,10 @@ Two implementations share one math definition:
   algorithm expressed in XLA ops).  This is what the multi-pod dry-run lowers:
   the host platform is CPU, so the Pallas TPU kernel cannot be compiled there;
   the blocked path has the same O(S·block) memory and the same collective
-  pattern.  On TPU the ``kernels.flash_attention`` Pallas kernel is selected
-  via ``impl='flash'``.
+  pattern.  Where ``kernels.ops.flash_fits`` holds (causal self-attention on
+  a TPU, outside mesh rules, whole 128-lane heads), ``impl='blocked'`` runs
+  the ``kernels.flash_attention`` Pallas kernels instead, forward and
+  backward; ``impl='flash'`` forces them.
 * ``decode_attention`` — single-token attention against a KV cache.
 """
 from __future__ import annotations
@@ -20,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops as kops
 from ..parallel.sharding import current_rules, lsc
 from .layers import apply_rope, lora
 from .params import P
@@ -276,8 +279,9 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
     b}) add low-rank terms to the projections, before rope.  With
     ``scope``, the projections, the core and the output projection run
     under the named scopes ``<scope>.qkv``, ``<scope>.attn``, ``<scope>.out``.
-    ``remat_blocks`` recomputes the blocked core's scores in the backward
-    pass (``blocked_attention``'s ``remat``).
+    ``remat_blocks`` recomputes the jnp blocked core's scores in the
+    backward pass (``blocked_attention``'s ``remat``); the flash kernel
+    saves no scores, so it has nothing to do there.
     """
     B, S, _ = x.shape
     is_cross = cross_x is not None or (cache is not None and cache.get("cross", False))
@@ -352,11 +356,10 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
         with _scope(scope, "attn"):
             if impl == "naive":
                 out = naive_attention(q, k, v, causal=causal, scale=scale)
-            elif impl == "flash":
-                from ..kernels import ops as kops
-                if scale is not None:         # the kernel scales by D ** -0.5
-                    q = q * (scale * math.sqrt(q.shape[-1]))
-                out = kops.flash_attention(q, k, v, causal=causal)
+            elif impl == "flash" or kops.flash_fits(q.shape, k.shape,
+                                                    causal=causal):
+                out = kops.flash_attention(q, k, v, causal=causal,
+                                           scale=scale)
             else:
                 out = blocked_attention(q, k, v, causal=causal,
                                         block=kv_block, scale=scale,

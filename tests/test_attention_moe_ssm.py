@@ -37,6 +37,67 @@ def test_blocked_vs_naive(sq, sk, h, kvh, block, causal, key):
                                rtol=2e-5, atol=2e-5)
 
 
+def _attn_cfg(head_dim):
+    return ModelConfig(name="t", family="dense", n_layers=1, d_model=32,
+                       n_heads=4, n_kv_heads=2, d_head=head_dim, d_ff=64,
+                       vocab_size=64)
+
+
+@pytest.mark.parametrize("case,runs_kernel", [
+    ("train", True),
+    ("prefill", True),
+    ("cross", False),
+    ("decode", False),
+    ("not_causal", False),
+    ("head_dim_64", False),
+    ("mesh_rules", False),
+    ("not_tpu", False),
+])
+def test_blocked_runs_the_flash_kernel_only_where_it_fits(case, runs_kernel,
+                                                           monkeypatch, key):
+    """attention_block's blocked path calls the flash kernel for causal
+    self-attention in train and prefill, on a TPU (patched in here), with
+    whole 128-lane heads and no mesh rules; elsewhere the jnp core."""
+    from repro.kernels import ops
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.attention import attention_block, attn_params
+    from repro.parallel.sharding import make_rules, use_rules
+
+    monkeypatch.setattr(ops, "_interpret", lambda: case == "not_tpu")
+    calls = []
+
+    def kernel(q, k, v, *, causal, scale):
+        calls.append(q.shape)
+        return naive_attention(q, k, v, causal=causal, scale=scale)
+
+    monkeypatch.setattr(ops, "flash_attention", kernel)
+    cfg = _attn_cfg(64 if case == "head_dim_64" else 128)
+    p = pr.init(attn_params(cfg), key)
+    x = jax.random.normal(key, (1, 16, 32), jnp.float32)
+    kw = dict(mode="train", causal=case != "not_causal")
+    if case == "prefill":
+        kw["mode"] = "prefill"
+    elif case == "cross":
+        kw["cross_x"] = jax.random.normal(key, (1, 8, 32), jnp.float32)
+    elif case == "decode":
+        cache = {n: jnp.zeros((1, 16, 2, 128)) for n in ("k", "v")}
+        kw.update(mode="decode", cache=cache, cache_pos=jnp.asarray(3))
+        x = x[:, :1]
+    rules = make_rules(make_host_mesh(1, 1)) if case == "mesh_rules" else None
+    with use_rules(rules):
+        attention_block(p, x, cfg, **kw)
+    assert bool(calls) == runs_kernel
+
+
+def test_flash_fits_needs_one_length(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    q = (1, 4096, 32, 128)
+    assert ops.flash_fits(q, q, causal=True)
+    assert not ops.flash_fits(q, (1, 2048, 32, 128), causal=True)
+
+
 def test_decode_attention_matches_naive_row(key):
     """decode of position p == row p of causal naive attention."""
     B, S, H, KVH, D = 2, 16, 4, 2, 32

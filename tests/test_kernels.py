@@ -12,7 +12,7 @@ import pytest
 pytestmark = pytest.mark.slow        # every test here compiles through jax
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.stream import EXPRS, elementwise, stream_triad
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
@@ -20,6 +20,11 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
 
 
 # ------------------------------------------------------------ flash attention
+def _bhsd(x):
+    """(B, S, H, D) <-> (B, H, S, D), for the (B, H, S, D) oracle."""
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("sq,sk,h,kvh,d", [
@@ -27,28 +32,97 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
     (256, 256, 4, 1, 64),        # MQA, multi-block
     (128, 384, 8, 2, 32),        # GQA, sk > sq (prefix decode style)
     (100, 200, 4, 2, 64),        # ragged (padding path)
+    (200, 200, 4, 1, 128),       # GQA at D = 128, ragged
 ])
 def test_flash_attention_vs_ref(sq, sk, h, kvh, d, causal, dtype, key):
-    if sq != sk and causal:
-        # causal with offset-free q over longer k: q token i attends k <= i
-        pass
+    # causal with offset-free q over longer k: q token i attends k <= i
     k1, k2, k3 = jax.random.split(key, 3)
-    q = jax.random.normal(k1, (2, h, sq, d), dtype)
-    k = jax.random.normal(k2, (2, kvh, sk, d), dtype)
-    v = jax.random.normal(k3, (2, kvh, sk, d), dtype)
-    out = flash_attention_bhsd(q, k, v, causal=causal, block_q=128,
-                               block_k=128, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
+    q = jax.random.normal(k1, (2, sq, h, d), dtype)
+    k = jax.random.normal(k2, (2, sk, kvh, d), dtype)
+    v = jax.random.normal(k3, (2, sk, kvh, d), dtype)
+    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
+                          interpret=True)
+    want = _bhsd(ref.flash_attention_ref(_bhsd(q), _bhsd(k), _bhsd(v),
+                                         causal=causal))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
 
+def _attn_inputs(key, S, H, G, D):
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    q = jax.random.normal(k1, (1, S, H, D), jnp.float32)
+    k = jax.random.normal(k2, (1, S, H // G, D), jnp.float32)
+    v = jax.random.normal(k3, (1, S, H // G, D), jnp.float32)
+    w = jax.random.normal(k4, (1, S, H, D), jnp.float32)
+    return q, k, v, w
+
+
+def _value_and_grads(attn, q, k, v, w):
+    """attn's output and the gradients of sum(w * output) in q, k, v."""
+    def loss(q, k, v):
+        out = attn(q, k, v)
+        return jnp.sum(w * out), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (out,) + grads
+
+
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("scale", [None, 0.125])
+@pytest.mark.parametrize("G", [1, 4])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_grads_vs_jnp(causal, G, scale, D, key):
+    """Output and dq, dk, dv of the differentiable kernel against the
+    models' naive and blocked jnp attention, at a length (136) that is a
+    multiple of neither block, with query and key blocks of different
+    sizes, so the causal index maps clamp."""
+    from repro.models.attention import blocked_attention, naive_attention
+
+    q, k, v, w = _attn_inputs(key, 136, 4, G, D)
+    got = _value_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, scale=scale, block_q=32, block_k=64,
+        interpret=True), q, k, v, w)
+    for oracle in (
+            lambda q, k, v: naive_attention(q, k, v, causal=causal,
+                                            scale=scale),
+            lambda q, k, v: blocked_attention(q, k, v, causal=causal,
+                                              block=64, scale=scale)):
+        want = _value_and_grads(oracle, q, k, v, w)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+
+
+def test_flash_attention_masked_tiles_contribute_nothing(key):
+    """Causal: keys and values past the first two 64-query tiles (whole
+    masked tiles for them) are perturbed; those queries' outputs and every
+    gradient of a loss over them stay bit for bit the same."""
+    q, k, v, w = _attn_inputs(key, 256, 4, 4, 64)
+    w = w.at[:, 128:].set(0.0)
+    noise = 10.0 * jax.random.normal(jax.random.fold_in(key, 1), k.shape)
+    beyond = (jnp.arange(256) >= 128)[None, :, None, None]
+
+    def run(k, v):
+        return _value_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=64, interpret=True),
+            q, k, v, w)
+
+    base = run(k, v)
+    moved = run(jnp.where(beyond, k + noise, k),
+                jnp.where(beyond, v - noise, v))
+    for a, b in zip(base, moved):
+        np.testing.assert_array_equal(np.asarray(a[:, :128]),
+                                      np.asarray(b[:, :128]))
+
+
 @pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 256)])
 def test_flash_attention_block_shape_invariance(block_q, block_k, key):
-    q = jax.random.normal(key, (1, 2, 256, 64), jnp.float32)
-    out_a = flash_attention_bhsd(q, q, q, causal=True, block_q=block_q,
-                                 block_k=block_k, interpret=True)
-    out_b = ref.flash_attention_ref(q, q, q, causal=True)
+    q = jax.random.normal(key, (1, 256, 2, 64), jnp.float32)
+    out_a = flash_attention(q, q, q, causal=True, block_q=block_q,
+                            block_k=block_k, interpret=True)
+    out_b = _bhsd(ref.flash_attention_ref(_bhsd(q), _bhsd(q), _bhsd(q),
+                                          causal=True))
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
                                rtol=2e-5, atol=2e-5)
 
@@ -57,10 +131,8 @@ def test_flash_attention_ops_layout(key):
     """ops.flash_attention uses (B, S, H, D) layout like the models."""
     q = jax.random.normal(key, (2, 128, 4, 64), jnp.float32)
     out = ops.flash_attention(q, q, q, causal=True)
-    want = jnp.transpose(
-        ref.flash_attention_ref(*(jnp.transpose(x, (0, 2, 1, 3))
-                                  for x in (q, q, q)), causal=True),
-        (0, 2, 1, 3))
+    want = _bhsd(ref.flash_attention_ref(_bhsd(q), _bhsd(q), _bhsd(q),
+                                         causal=True))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
