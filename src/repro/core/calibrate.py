@@ -357,8 +357,8 @@ def _knob_spec(hw: HardwareSpec, w: int, mw: int, vw: int,
 def sweep_o3(table: AccuracyTable, hw: HardwareSpec,
              windows=O3_WINDOWS, mem_widths=O3_MEM_WIDTHS,
              queue_depths=O3_QUEUE_DEPTHS, vpu_widths=O3_VPU_WIDTHS,
-             compute_dtype: str = "f64", backend: str = "numpy",
-             core_counts=(1,), topology=None) -> "O3Sweep":
+             compute_dtype: str = "f64", core_counts=(1,),
+             topology=None) -> "O3Sweep":
     """Re-schedule already-measured programs under each knob combination
     (no re-measurement, no recompilation) and rank combos by mean |diff|
     of the schedule engine vs the measured wall times.
@@ -366,18 +366,15 @@ def sweep_o3(table: AccuracyTable, hw: HardwareSpec,
     The whole grid runs BATCHED (``core.compiled.schedule_batch``): each
     program is compiled once to array form, shared across every combo, and
     one sequential pass per program advances all combos in lockstep — the
-    knob grid is a vector axis, not a python loop.  ``backend="jax"``
-    runs the same pass as a jit-ed ``lax.scan`` on the accelerator.
+    knob grid is a vector axis, not a python loop.
 
     ``core_counts`` adds the node engine's core count as a sweep axis:
     each count > 1 runs the batched node engine
     (``core.node.schedule_node_batch``, shard partition), which carries
-    every knob combo through its own contention fixpoint — exact
-    per-knob contention, not the old one-shot ``shard_costed``
-    approximation.  Rows against single-core measurements are only
-    comparable at ``n_cores=1``; the extra counts chart the knob grid's
-    scaling behaviour (and ``best`` is picked among the smallest swept
-    core count).
+    every knob combo through its own contention fixpoint.  Rows against
+    single-core measurements are only comparable at ``n_cores=1``; the
+    extra counts chart the knob grid's scaling behaviour (and ``best`` is
+    picked among the smallest swept core count).
 
     Requires a table built with ``keep_programs=True``.  Returns an
     :class:`O3Sweep` (ranked results + the tuned ``HardwareSpec``).
@@ -406,12 +403,11 @@ def sweep_o3(table: AccuracyTable, hw: HardwareSpec,
         t_by_count = {}
         if 1 in core_counts:
             cp = compile_program(prog, hw, compute_dtype=compute_dtype)
-            t_by_count[1] = schedule_batch(cp, knobs, backend=backend)
+            t_by_count[1] = schedule_batch(cp, knobs)
         if node_counts:
             sw = schedule_node_sweep(
                 compile_node(prog, hw, compute_dtype=compute_dtype),
-                hw, knobs, node_counts, topology, partition="shard",
-                backend=backend)
+                hw, knobs, node_counts, topology, partition="shard")
             t_by_count.update(zip(node_counts, sw))
         for ci, n_cores in enumerate(core_counts):
             t_us = t_by_count[n_cores] * 1e6

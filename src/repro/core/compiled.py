@@ -16,10 +16,7 @@ def-use edges, packed O3 knobs) and every downstream consumer runs on it:
 * :func:`schedule_batch` — the batched sweep engine: the whole O3 knob
   grid is a batch axis; one sequential pass over the ops advances every
   knob combination in lockstep with NumPy vector ops, so enlarging the
-  grid (windows up to 1024, per-port widths) is ~free,
-* :func:`schedule_batch_jax` — the same in-order list scheduler as a
-  ``jax.lax.scan`` (``vmap``-ed over the knob axis and ``jit``-ed), so
-  the simulator itself can run on the accelerator it models.
+  grid (windows up to 1024, per-port widths) is ~free.
 
 Every kernel replays the reference scheduler's float operations in the
 same order, so ``t_est`` is bit-identical to ``core.schedule``'s
@@ -132,7 +129,6 @@ class CompiledProgram:
     port_id: np.ndarray          # [n] int8 into PORTS; -1 = uncosted
     dep_indptr: np.ndarray       # [n+1] CSR over valid (j < i) edges
     dep_indices: np.ndarray      # [E]
-    pos_in_port: np.ndarray      # [n] running issue index on the op's port
     port_counts: np.ndarray      # [len(PORTS)] ops issued per port
     # knob-independent schedule invariants
     t_serial: float
@@ -180,7 +176,6 @@ def compile_program(prog: Program, hw: HardwareSpec,
     startup = hw.op_startup_ns * 1e-9
     durations = np.zeros(n, dtype=np.float64)
     port_id = np.full(n, -1, dtype=np.int8)
-    pos_in_port = np.zeros(n, dtype=np.int64)
     port_counts = np.zeros(len(PORTS), dtype=np.int64)
     indptr = np.zeros(n + 1, dtype=np.int64)
     indices: List[int] = []
@@ -203,7 +198,6 @@ def compile_program(prog: Program, hw: HardwareSpec,
         pid = _PORT_ID[ot.port]
         durations[i] = dur
         port_id[i] = pid
-        pos_in_port[i] = port_counts[pid]
         port_counts[pid] += 1
         port_busy[ot.port] = port_busy.get(ot.port, 0.0) + dur
         t_serial += dur
@@ -228,7 +222,7 @@ def compile_program(prog: Program, hw: HardwareSpec,
     cp = CompiledProgram(
         n=n, durations=durations, port_id=port_id,
         dep_indptr=indptr, dep_indices=np.array(indices, dtype=np.int64),
-        pos_in_port=pos_in_port, port_counts=port_counts,
+        port_counts=port_counts,
         t_serial=t_serial, t_dataflow=t_dataflow, n_ops=n_ops,
         n_edges=n_edges, port_busy=port_busy,
         knobs=O3Knobs.single(hw),
@@ -327,19 +321,14 @@ def schedule_arrays(cp: CompiledProgram, hw: HardwareSpec
 
 
 # ------------------------------------------------------ batched numpy kernel
-def schedule_batch(cp: CompiledProgram, knobs: O3Knobs,
-                   backend: str = "numpy") -> np.ndarray:
+def schedule_batch(cp: CompiledProgram, knobs: O3Knobs) -> np.ndarray:
     """Schedule every knob combination in ``knobs`` against the shared
     compiled program in ONE sequential pass over the ops (the knob grid is
     the vector axis of every state update).  Returns ``t_est`` per combo,
     bit-identical to running the scalar kernel per combination."""
     uk, inv = knobs.unique()
     if uk is not knobs:               # clamped grids alias rows: schedule
-        return schedule_batch(cp, uk, backend)[inv]   # each combo once
-    if backend == "jax":
-        return schedule_batch_jax(cp, knobs)
-    if backend != "numpy":
-        raise ValueError(f"unknown schedule backend {backend!r}")
+        return schedule_batch(cp, uk)[inv]   # each combo once
     B = knobs.batch
     n = cp.n
     t_est = np.zeros(B, dtype=np.float64)
@@ -406,89 +395,3 @@ def schedule_batch(cp: CompiledProgram, knobs: O3Knobs,
         rt[i] = rt_prev
         np.maximum(t_est, finish, out=t_est)
     return t_est
-
-
-# --------------------------------------------------------- jax.lax.scan form
-def schedule_batch_jax(cp: CompiledProgram, knobs: O3Knobs) -> np.ndarray:
-    """The in-order list scheduler as a ``jax.lax.scan``, ``vmap``-ed over
-    the knob batch and ``jit``-ed — the simulator running on the
-    accelerator it models.  Pads the CSR edge lists to the max in-degree
-    and the pipes/history state to the batch's max width/port counts.
-
-    Runs in x64 so the result matches the NumPy kernels to float64
-    precision; returns a NumPy array of ``t_est`` per combo.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    n = cp.n
-    B = knobs.batch
-    if n == 0 or B == 0:
-        return np.zeros(B, dtype=np.float64)
-    P = len(PORTS)
-    indptr = cp.dep_indptr
-    deg = np.diff(indptr)
-    maxdeg = max(1, int(deg.max()) if n else 1)
-    deps_pad = np.full((n, maxdeg), -1, dtype=np.int64)
-    for i in range(n):
-        lo, hi = indptr[i], indptr[i + 1]
-        deps_pad[i, : hi - lo] = cp.dep_indices[lo:hi]
-    port_eff = np.maximum(cp.port_id.astype(np.int64), 0)
-    costed = cp.port_id >= 0
-    max_hist = max(1, int(cp.port_counts.max()))
-    wmax = max(1, int(knobs.width.max()))
-
-    with jax.enable_x64(True):
-        xs = (jnp.asarray(cp.durations), jnp.asarray(port_eff),
-              jnp.asarray(costed), jnp.asarray(deps_pad),
-              jnp.asarray(cp.pos_in_port), jnp.arange(n))
-
-        def one_combo(window, width, depth):
-            pipes0 = jnp.where(jnp.arange(wmax)[None, :] < width[:, None],
-                               0.0, jnp.inf)
-            carry0 = (jnp.zeros(n), jnp.zeros(n), 0.0,
-                      pipes0, jnp.zeros((P, max_hist)), 0.0)
-
-            def body(carry, x):
-                fin_arr, rt_arr, rt_prev, pipes, hist, t_best = carry
-                dur, pid, is_costed, deps, pos, i = x
-                ready = jnp.max(jnp.where(deps >= 0,
-                                          fin_arr[jnp.clip(deps, 0)], 0.0))
-                row = pipes[pid]
-                pf = row.min()
-                widx = i - window
-                wt = jnp.where(widx >= 0, rt_arr[jnp.clip(widx, 0)], 0.0)
-                qidx = pos - depth[pid]
-                qt = jnp.where(qidx >= 0, hist[pid, jnp.clip(qidx, 0)], 0.0)
-                start = jnp.maximum(jnp.maximum(ready, pf),
-                                    jnp.maximum(wt, qt))
-                finish = start + dur
-                fin_i = jnp.where(is_costed, finish, ready)
-                lane = row.argmin()
-                pipes = jnp.where(is_costed,
-                                  pipes.at[pid, lane].set(finish), pipes)
-                hist = jnp.where(is_costed,
-                                 hist.at[pid, pos].set(start), hist)
-                rt_prev = jnp.maximum(rt_prev, fin_i)
-                t_best = jnp.where(is_costed,
-                                   jnp.maximum(t_best, finish), t_best)
-                return (fin_arr.at[i].set(fin_i), rt_arr.at[i].set(rt_prev),
-                        rt_prev, pipes, hist, t_best), None
-
-            (_, _, _, _, _, t_best), _ = jax.lax.scan(body, carry0, xs)
-            return t_best
-
-        # the jitted fn closes over THIS program's arrays (and the padded
-        # lane count): cache it on the CompiledProgram, keyed by wmax, so
-        # it can never serve another program or a wider knob batch
-        fns = getattr(cp, "_jax_fns", None)
-        if fns is None:
-            fns = {}
-            cp._jax_fns = fns
-        fn = fns.get(wmax)
-        if fn is None:
-            fn = jax.jit(jax.vmap(one_combo))
-            fns[wmax] = fn
-        out = fn(jnp.asarray(knobs.window), jnp.asarray(knobs.width),
-                 jnp.asarray(knobs.depth))
-        return np.asarray(out, dtype=np.float64)

@@ -34,7 +34,6 @@ test harness).
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -939,97 +938,6 @@ def _node_pass_batch(nb: NodeCompiledBatch, durs_cols: np.ndarray,
     return np.max(finishes, axis=0, where=cm[:, None], initial=0.0)
 
 
-def _node_pass_batch_jax(nb: NodeCompiledBatch, durs_cols: np.ndarray,
-                         window: np.ndarray, width: np.ndarray,
-                         depth: np.ndarray) -> np.ndarray:
-    """``jax.lax.scan`` variant of :func:`_node_pass_batch` (the
-    ``schedule_batch_jax`` pattern, vmapped over batch elements in
-    x64): one fused XLA program per (structure, ring sizes) — agreeing
-    with the numpy kernel to float tolerance, not bit-exactly (XLA may
-    reassociate).  The jitted fn is cached on the batch form."""
-    import jax
-    import jax.numpy as jnp
-
-    nc = nb.nc
-    n = nc.n
-    M = len(window)
-    if n == 0 or M == 0:
-        return np.zeros(M)
-    P = len(PORTS)
-    wmax = max(1, int(width.max()))
-    max_core_ops = max(1, int(nb.core_ops.max()))
-    max_cp = max(1, int(nb.cp_counts.max()))
-    S = nb.n_streams
-    key = (wmax, max_core_ops, max_cp)
-    fns = nb.__dict__.setdefault("_jax_fns", {})
-    fn = fns.get(key)
-    if fn is None:
-        indptr = nc.cp.dep_indptr
-        deg = np.diff(indptr)
-        maxdeg = max(1, int(deg.max()) if n else 1)
-        deps_pad = np.full((n, maxdeg), -1, dtype=np.int64)
-        extra_pad = np.zeros((n, maxdeg))
-        for i in range(n):
-            lo, hi = int(indptr[i]), int(indptr[i + 1])
-            deps_pad[i, :hi - lo] = nc.cp.dep_indices[lo:hi]
-            if nb.edge_extra is not None:
-                extra_pad[i, :hi - lo] = nb.edge_extra[lo:hi]
-        port_eff = np.maximum(nc.cp.port_id.astype(np.int64), 0)
-        costed = nc.cp.port_id >= 0
-        row_port = np.arange(S * P, dtype=np.int64) % P
-
-        def one(win, wid, dep, durs):
-            pipes0 = jnp.where(
-                jnp.arange(wmax)[None, :] < wid[row_port][:, None],
-                0.0, jnp.inf)
-            carry0 = (jnp.zeros(n), jnp.zeros((S, max_core_ops)),
-                      jnp.zeros(S), pipes0, jnp.zeros((S * P, max_cp)),
-                      0.0)
-            xs = (jnp.arange(n), jnp.asarray(durs),
-                  jnp.asarray(port_eff), jnp.asarray(costed),
-                  jnp.asarray(deps_pad), jnp.asarray(extra_pad),
-                  jnp.asarray(nb.sched_core_of), jnp.asarray(nb.cpid),
-                  jnp.asarray(nb.pos_in_core), jnp.asarray(nb.pos_in_cp))
-
-            def body(carry, x):
-                fin, rt, rt_tail, pipes, hist, t_best = carry
-                (i, dur, pid, is_costed, deps, extras, c, cp_i, pos,
-                 poscp) = x
-                ready = jnp.max(jnp.where(
-                    deps >= 0, fin[jnp.clip(deps, 0)] + extras, 0.0))
-                row = pipes[cp_i]
-                pf = row.min()
-                widx = pos - win
-                wt = jnp.where(widx >= 0, rt[c, jnp.clip(widx, 0)], 0.0)
-                qidx = poscp - dep[pid]
-                qt = jnp.where(qidx >= 0,
-                               hist[cp_i, jnp.clip(qidx, 0)], 0.0)
-                start = jnp.maximum(jnp.maximum(ready, pf),
-                                    jnp.maximum(wt, qt))
-                finish = start + dur
-                fin_i = jnp.where(is_costed, finish, ready)
-                pipes = pipes.at[cp_i, row.argmin()].set(
-                    jnp.where(is_costed, finish, row[row.argmin()]))
-                hist = hist.at[cp_i, poscp].set(
-                    jnp.where(is_costed, start, hist[cp_i, poscp]))
-                tail = jnp.maximum(rt_tail[c], fin_i)
-                t_best = jnp.where(is_costed,
-                                   jnp.maximum(t_best, finish), t_best)
-                return (fin.at[i].set(fin_i), rt.at[c, pos].set(tail),
-                        rt_tail.at[c].set(tail), pipes, hist,
-                        t_best), None
-
-            (_, _, _, _, _, t), _ = jax.lax.scan(body, carry0, xs)
-            return t
-
-        fn = fns[key] = jax.jit(jax.vmap(one, in_axes=(0, 0, 0, 0)))
-    with jax.enable_x64(True):
-        out = fn(jnp.asarray(window), jnp.asarray(width),
-                 jnp.asarray(depth),
-                 jnp.asarray(np.ascontiguousarray(durs_cols.T)))
-        return np.asarray(out)
-
-
 @dataclass
 class NodeBatchResult:
     """Per-element results of a batched node run: contention-aware
@@ -1087,8 +995,7 @@ def _clone_context(tmpl: dict) -> dict:
 
 
 def _fixpoint_batch(nb: NodeCompiledBatch, contexts: List[dict],
-                    knobs, max_iters: int, tol: float,
-                    backend: str) -> NodeBatchResult:
+                    knobs, max_iters: int, tol: float) -> NodeBatchResult:
     """The bandwidth-contention fixpoint as a vectorized outer loop over
     the batched pass: every element carries its own ``n_active`` state
     machine (replaying the scalar ``schedule_node`` trajectory exactly —
@@ -1104,12 +1011,6 @@ def _fixpoint_batch(nb: NodeCompiledBatch, contexts: List[dict],
     iters = np.zeros(M, dtype=np.int64)
     if n == 0 or M == 0:
         return NodeBatchResult(t_est, t_zero, iters)
-    pass_fn = _node_pass_batch_jax if backend == "jax" \
-        else _node_pass_batch
-    # the numpy pass compacts converged elements out of later passes;
-    # the jax pass keeps the full batch (a shrinking batch axis would
-    # re-trace the jitted scan per distinct size)
-    compact = backend != "jax"
     durs_cols = np.empty((n, M))
     done = np.zeros(M, dtype=bool)
     final = np.fromiter((not ctx["contended"] for ctx in contexts),
@@ -1135,14 +1036,9 @@ def _fixpoint_batch(nb: NodeCompiledBatch, contexts: List[dict],
                     nc_m, row, row_w, ctx["scale"])
             stale[m] = False
         idx = np.nonzero(active)[0]
-        if compact:
-            t = pass_fn(nb, durs_cols[:, idx], knobs.window[idx],
-                        knobs.width[idx], knobs.depth[idx])
-            t_est[idx] = t
-        else:
-            t = pass_fn(nb, durs_cols, knobs.window, knobs.width,
-                        knobs.depth)
-            t_est[idx] = t[idx]
+        t_est[idx] = _node_pass_batch(nb, durs_cols[:, idx],
+                                      knobs.window[idx], knobs.width[idx],
+                                      knobs.depth[idx])
         iters[idx] += 1
         if first:
             t_zero[:] = t_est           # pass 1 runs every element
@@ -1169,20 +1065,19 @@ def schedule_node_batch(nc: NodeCompiled, hw: HardwareSpec, knobs,
                         topology: Optional[NodeTopology] = None,
                         partition: str = "shard",
                         core_of: Optional[np.ndarray] = None,
-                        max_iters: int = 8, tol: float = 1e-2,
-                        backend: str = "numpy") -> NodeBatchResult:
+                        max_iters: int = 8, tol: float = 1e-2
+                        ) -> NodeBatchResult:
     """Batched node engine: one contention-aware node estimate per knob
     combo in ``knobs`` (an :class:`~.compiled.O3Knobs` batch), all
     combos advancing in lockstep through the vectorized pass.  Each
     element is bit-identical to ``schedule_node`` under a spec carrying
-    the same knobs (``backend="jax"`` trades bit-exactness for a fused
-    ``lax.scan``).  Duplicate knob rows (clamp-collapsed grid points)
+    the same knobs.  Duplicate knob rows (clamp-collapsed grid points)
     are scheduled once and expanded back to the full grid."""
     uk, inv = knobs.unique()
     nb = compile_node_batch(nc, hw, n_cores, topology, partition, core_of)
     tmpl = _batch_context(nb, n_cores)
     contexts = [_clone_context(tmpl) for _ in range(uk.batch)]
-    res = _fixpoint_batch(nb, contexts, uk, max_iters, tol, backend)
+    res = _fixpoint_batch(nb, contexts, uk, max_iters, tol)
     if uk is knobs:
         return res
     return NodeBatchResult(res.t_est[inv], res.t_zero_contention[inv],
@@ -1193,8 +1088,7 @@ def schedule_node_batch(nc: NodeCompiled, hw: HardwareSpec, knobs,
 def schedule_node_sweep(nc: NodeCompiled, hw: HardwareSpec, knobs,
                         core_counts, topology: Optional[NodeTopology] = None,
                         partition: str = "shard", max_iters: int = 8,
-                        tol: float = 1e-2,
-                        backend: str = "numpy") -> np.ndarray:
+                        tol: float = 1e-2) -> np.ndarray:
     """Core-count x knob-grid sweep as one fused batch: ``t_est [C, B]``
     seconds.  Shard mode (the zoo's) shares one batch form across every
     core count — the whole sweep is a single ``C*B``-element run of the
@@ -1212,12 +1106,10 @@ def schedule_node_sweep(nc: NodeCompiled, hw: HardwareSpec, knobs,
         tmpls = {k: _batch_context(nb, k) for k in core_counts}
         contexts = [_clone_context(tmpls[k])
                     for k in core_counts for _ in range(B)]
-        res = _fixpoint_batch(nb, contexts, tiled, max_iters, tol,
-                              backend)
+        res = _fixpoint_batch(nb, contexts, tiled, max_iters, tol)
         return res.t_est.reshape(len(core_counts), B)[:, inv]
     rows = [schedule_node_batch(nc, hw, knobs, k, topology, partition,
-                                max_iters=max_iters, tol=tol,
-                                backend=backend).t_est
+                                max_iters=max_iters, tol=tol).t_est
             for k in core_counts]
     return np.stack(rows)
 
@@ -1310,8 +1202,7 @@ def compile_node_grid(prog: Program, grid: SpecGrid,
 def schedule_spec_sweep(ngc: NodeGridCompiled,
                         knobs: Optional[O3Knobs] = None,
                         core_counts=None, max_iters: int = 8,
-                        tol: float = 1e-2,
-                        backend: str = "numpy") -> np.ndarray:
+                        tol: float = 1e-2) -> np.ndarray:
     """Fused spec × core-count × knob sweep: ``t_est [S, C, B]``.
 
     Shard partition only (the DSE mode: every core runs the stream at
@@ -1366,7 +1257,7 @@ def schedule_spec_sweep(ngc: NodeGridCompiled,
     tiled = O3Knobs(window=np.tile(uk.window, S * C),
                     width=np.tile(uk.width, (S * C, 1)),
                     depth=np.tile(uk.depth, (S * C, 1)))
-    res = _fixpoint_batch(nb, contexts, tiled, max_iters, tol, backend)
+    res = _fixpoint_batch(nb, contexts, tiled, max_iters, tol)
     return res.t_est.reshape(S, C, B)[:, :, inv]
 
 
@@ -1381,45 +1272,3 @@ def simulate_node(prog: Program, hw: HardwareSpec, n_cores: int,
     entry point's ``engine="node"`` backend)."""
     nc = compile_node(prog, hw, links_per_collective, compute_dtype, costed)
     return schedule_node(nc, hw, n_cores, topology, partition, **kw)
-
-
-def shard_costed(prog: Program, hw: HardwareSpec, n_cores: int,
-                 topology: Optional[NodeTopology] = None,
-                 links_per_collective: int = 2,
-                 compute_dtype: Optional[str] = None
-                 ) -> List[Optional[OpTime]]:
-    """The shard-mode node model as a costed list: per-op times scaled by
-    1/n_cores with the converged contention applied, suitable for
-    ``compile_program(costed=...)`` — this is how the O3 knob sweep rides
-    ``schedule_batch`` with core count as an extra grid axis (the knob
-    grid batches over one shard-contended compiled program per core
-    count)."""
-    nc = compile_node(prog, hw, links_per_collective, compute_dtype)
-    nr = schedule_node(nc, hw, n_cores, topology, partition="shard")
-    topo = nr.topology
-    cores = np.arange(n_cores, dtype=np.int64)
-    # rebuild the converged per-level inverse bandwidths from the report
-    n_active = []
-    for li, nm in enumerate(nc.level_names):
-        n_dom = int(np.ceil(n_cores / nc.shared_by[li]))
-        na = np.ones(n_dom)
-        for cs in nr.per_cmg:
-            if nm in cs.n_active:
-                na[:] = cs.n_active[nm]
-                break
-        n_active.append(na)
-    inv_r, inv_w = _eff_inv(nc, topo, cores, n_active)
-    scale = 1.0 / n_cores
-    t_mem = ((nc.rd * inv_r[0]).sum(axis=1)
-             + (nc.wr * inv_w[0]).sum(axis=1)) * scale + nc.lat
-    base = cost_program(prog, hw, links_per_collective, compute_dtype)
-    out: List[Optional[OpTime]] = []
-    for i, ot in enumerate(base):
-        if ot is None:
-            out.append(None)
-            continue
-        out.append(dataclasses.replace(
-            ot, t_compute=ot.t_compute * scale,
-            t_mem=float(t_mem[i]) if ot.traffic is not None else 0.0,
-            t_ici=ot.t_ici))
-    return out

@@ -500,8 +500,7 @@ def sampled_node_sweep(prog: Program, hw: HardwareSpec, knobs,
                        config: Optional[SamplingConfig] = None,
                        compute_dtype: Optional[str] = None,
                        costed: Optional[List[Optional[OpTime]]] = None,
-                       plan: Optional[SamplePlan] = None,
-                       backend: str = "numpy"
+                       plan: Optional[SamplePlan] = None
                        ) -> Tuple[np.ndarray, SamplePlan]:
     """Sampled core-count x knob sweep: each representative rides the
     batched node engine (``schedule_node_sweep``), and the ``[C, B]``
@@ -512,8 +511,7 @@ def sampled_node_sweep(prog: Program, hw: HardwareSpec, knobs,
     out = np.zeros((len(core_counts), knobs.batch))
     for c, nc in enumerate(_rep_node_forms(plan, hw, compute_dtype)):
         t = schedule_node_sweep(nc, hw, knobs, core_counts,
-                                topology=topology, partition=partition,
-                                backend=backend)
+                                topology=topology, partition=partition)
         out += plan.weights[c] * t
     return out, plan
 

@@ -102,14 +102,8 @@ def batch_shardings(model: LM, shape: ShapeConfig, rules: MeshRules,
 
 def build_cell(arch: str, shape_name: str, mesh: Mesh, *,
                run_overrides: Optional[dict] = None,
-               rule_overrides: Optional[dict] = None,
-               act_rule_overrides: Optional[dict] = None,
-               model_overrides: Optional[dict] = None,
-               attn_impl: str = "blocked",
-               ssd_impl: Optional[str] = None) -> Cell:
+               act_rule_overrides: Optional[dict] = None) -> Cell:
     cfg = ARCHS[arch]
-    if model_overrides:
-        cfg = dataclasses.replace(cfg, **model_overrides)
     shape = SHAPES[shape_name]
     if shape not in shapes_for(cfg):
         raise ValueError(f"{shape_name} is skipped for {arch} "
@@ -118,18 +112,10 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh, *,
     if act_rule_overrides is None and arch in SP_ARCHS \
             and shape.kind != "decode":
         act_rule_overrides = SP_ACT_RULES
-    rules = make_rules(mesh, rule_overrides, act_rule_overrides)
-    if ssd_impl is None:
-        # On TPU the Pallas SSD kernel is the production path; the DRY-RUN
-        # keeps the jnp lowering because interpret-mode pallas emulates the
-        # grid as a while loop with full-buffer copies per step — an
-        # artifact Mosaic does not have (§Perf iteration C quantifies the
-        # kernel's true cost with benchmarks/ssd_kernel_cost.py instead).
-        ssd_impl = "jnp"
+    rules = make_rules(mesh, act_overrides=act_rule_overrides)
     kv_dtype = ("int8" if arch in KV_INT8_ARCHS and shape.kind == "decode"
                 else "bf16")
-    model = build_model(cfg, attn_impl=attn_impl, ssd_impl=ssd_impl,
-                        kv_cache_dtype=kv_dtype)
+    model = build_model(cfg, kv_cache_dtype=kv_dtype)
     pdt = jnp.dtype(run.param_dtype)
 
     param_specs = model.param_specs()

@@ -1,11 +1,13 @@
 """JAX's persistent compilation cache, placed from outside or at a fixed path.
 
-Entry points that compile (``chip_smoke.py``, the benchmarks) call
-:func:`use_compile_cache` once, before their first compile.  Where
-``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module sets
-no other directory.  Otherwise the cache goes to ``experiments/jax_cache/``
-under the checkout, a path derived from this package's location so that it is
-the same on every run (the path is part of what makes a cache hit).
+Entry points that compile (the benchmark, ``python3 bench/run.py --workload
+<cell> --seed <n> --seconds 10 --trace <0|1>``, and the sweeps under
+``benchmarks/``) call :func:`use_compile_cache` once, before their first
+compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+this module sets no other directory.  Otherwise the cache goes to
+``experiments/jax_cache/`` under the checkout, a path derived from this
+package's location so that it is the same on every run (the path is part of
+what makes a cache hit).
 """
 from __future__ import annotations
 

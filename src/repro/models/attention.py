@@ -9,7 +9,7 @@ Two implementations share one math definition:
   pattern.  Where ``kernels.ops.flash_fits`` holds (causal self-attention on
   a TPU, outside mesh rules, whole 128-lane heads), ``impl='blocked'`` runs
   the ``kernels.flash_attention`` Pallas kernels instead, forward and
-  backward; ``impl='flash'`` forces them.
+  backward.
 * ``decode_attention`` — single-token attention against a KV cache.
 """
 from __future__ import annotations
@@ -356,8 +356,7 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
         with _scope(scope, "attn"):
             if impl == "naive":
                 out = naive_attention(q, k, v, causal=causal, scale=scale)
-            elif impl == "flash" or kops.flash_fits(q.shape, k.shape,
-                                                    causal=causal):
+            elif kops.flash_fits(q.shape, k.shape, causal=causal):
                 out = kops.flash_attention(q, k, v, causal=causal,
                                            scale=scale)
             else:
