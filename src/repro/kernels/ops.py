@@ -52,52 +52,56 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
     x: (B,L,H,P); dt: (B,L,H) post-softplus; A: (H,); Bm, Cm: (B,L,G,N)
     at the group count, H % G == 0.  Returns (y, final_state (B,H,P,N)).
+    x and y pass through the kernel as (B, L, H·P), a free reshape of the
+    mixer's arrays, and the chunk states as (nc, B, N, H·P): head h owns
+    lanes [h·P, (h+1)·P) of every one of them.
     """
     B, L, H, P = x.shape
     G, N = Bm.shape[-2:]
     hpg = H // G
     Q = min(chunk, L)
     pad = (-L) % Q
+    x = x.reshape(B, L, H * P)
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
     Lp = L + pad
     nc = Lp // Q
-    xc = x.reshape(B, nc, Q, H, P)
     dtc = dt.reshape(B, nc, Q, H)
     Bc = Bm.reshape(B, nc, Q, G, N)
     Cc = Cm.reshape(B, nc, Q, G, N)
 
     dA = dtc.astype(jnp.float32) * A.astype(jnp.float32)
     cs = jnp.cumsum(dA, axis=2)                            # (B,nc,Q,H)
-    y_diag, states = _ssd.ssd_chunk_pallas(
-        xc, dtc, cs, Bc, Cc, interpret=_interpret())
+    y_diag, states, e_in = _ssd.ssd_chunk_pallas(
+        x, dtc, cs, Bc, Cc, interpret=_interpret())
 
     # inter-chunk recurrence (linear in nc)
     def step(carry, inp):
-        s_c, g = inp                                       # (B,H,N,P), (B,H)
-        new = carry * g[..., None, None] + s_c
+        s_c, g = inp                                       # (B,N,H·P), (B,H·P)
+        new = carry * g[:, None, :] + s_c
         return new, carry
 
     with jax.named_scope("mixer.ssd_state"):
-        gamma = jnp.exp(cs[:, :, -1])                      # (B,nc,H)
-        init = (jnp.zeros((B, H, N, P), jnp.float32) if initial_state is None
-                else jnp.moveaxis(initial_state, -1, -2).astype(jnp.float32))
+        gamma = jnp.repeat(jnp.exp(cs[:, :, -1]), P, axis=-1)  # (B,nc,H·P)
+        init = (jnp.zeros((B, N, H * P), jnp.float32) if initial_state is None
+                else jnp.transpose(initial_state, (0, 3, 1, 2)).reshape(
+                    B, N, H * P).astype(jnp.float32))
         final, prev = jax.lax.scan(step, init,
-                                   (jnp.moveaxis(states, 1, 0),
-                                    jnp.moveaxis(gamma, 1, 0)))
-        prev = jnp.moveaxis(prev, 0, 1)                    # (B,nc,H,N,P)
+                                   (states, jnp.moveaxis(gamma, 1, 0)))
 
         # inter-chunk output: exp(cs_i) * C_i . prev_state, one
-        # (Q,N)x(N,hpg·P) product per group
-        y_off = jnp.einsum("bcign,bcgknp->bcigkp", Cc.astype(jnp.float32),
-                           prev.reshape(B, nc, G, hpg, N, P))
-        y_off = y_off.reshape(B, nc, Q, H, P) * jnp.exp(cs)[..., None]
+        # (Q,N)x(N,hpg·P) product per group, straight into (B, Lp, H·P),
+        # scaled by the kernel's e_in = exp(cs_i) over each head's lanes
+        y_off = jnp.einsum("bcign,cbngk->bcigk", Cc.astype(jnp.float32),
+                           prev.reshape(nc, B, N, G, hpg * P))
+        y_off = y_off.reshape(B, Lp, H * P) * e_in
 
-        y = (y_diag.astype(jnp.float32) + y_off).reshape(B, Lp, H, P)[:, :L]
-        return y.astype(x.dtype), jnp.moveaxis(final, -1, -2).astype(x.dtype)
+        y = (y_diag.astype(jnp.float32) + y_off)[:, :L].reshape(B, L, H, P)
+        final = jnp.transpose(final.reshape(B, N, H, P), (0, 2, 3, 1))
+        return y.astype(x.dtype), final.astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("name", "block"))

@@ -14,6 +14,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -48,7 +49,7 @@ def _shapes(one_chip, dtype, *shapes):
 
 
 def _ssd_args(one_chip, nc=2):
-    return _shapes(one_chip, jnp.float32, (1, nc, Q, H, P), (1, nc, Q, H),
+    return _shapes(one_chip, jnp.float32, (1, nc * Q, H * P), (1, nc, Q, H),
                    (1, nc, Q, H), (1, nc, Q, H, N), (1, nc, Q, H, N))
 
 
@@ -62,8 +63,8 @@ def test_ssd_forward_compiles_for_v5e(one_chip):
 
 def test_ssd_forward_backward_compiles_for_v5e(one_chip):
     def loss(*args):
-        y, s = ssd_scan.ssd_chunk_pallas(*args, interpret=False)
-        return jnp.sum(jnp.sin(y)) + jnp.sum(s * s)
+        y, s, e = ssd_scan.ssd_chunk_pallas(*args, interpret=False)
+        return jnp.sum(jnp.sin(y) * e) + jnp.sum(s * s)
 
     step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
     text = jax.jit(step).lower(*_ssd_args(one_chip)).compile().as_text()
@@ -109,6 +110,51 @@ def test_ssd_groups_reach_the_kernel_unbroadcast(one_chip, monkeypatch):
                                         b"_ssd_chunk_bwd_kernel")
             if re.search(rb"\b" + n + rb"\b", b)} == {
         b"_ssd_chunk_kernel", b"_ssd_chunk_bwd_kernel"}
+
+
+def _ssd_step_shapes(text):
+    """Each f32 array shape written in a compiled step's text."""
+    return {tuple(int(d) for d in m.group(1).split(","))
+            for m in re.finditer(r"f32\[(\d[\d,]*)\]", text)}
+
+
+def test_ssd_keeps_the_mixer_layout_through_the_kernel(one_chip,
+                                                        monkeypatch):
+    """mamba2-1.3b's widths through ops.ssd_scan forward and backward, x and
+    y in the mixer's (B, L, H·P) layout as the conv makes and the gate
+    reads them: the kernels take x and dy and give y and dx as they are, so
+    no array of the step holds x's elements per head, as (..., Q, P) tiles
+    or a 5-D (..., H, Q, P) / (..., Q, H, P) view, nor per-head (..., N, P)
+    chunk states, and no copy or transpose moves an x-sized array."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    nc = 2
+    L = nc * Q
+    x, dt, Bm, Cm = _shapes(one_chip, jnp.float32, (1, L, H * P), (1, L, H),
+                            (1, L, 1, N), (1, L, 1, N))
+    A, = _shapes(one_chip, jnp.float32, (H,))
+
+    def loss(x, dt, A, Bm, Cm):
+        y, s = ops.ssd_scan(x.reshape(1, L, H, P), dt, A, Bm, Cm, chunk=Q)
+        return jnp.sum(jnp.sin(y.reshape(1, L, H * P))) + jnp.sum(s * s)
+
+    step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(step).lower(x, dt, A, Bm, Cm).compile().as_text()
+    assert text.count("tpu_custom_call") == 2          # forward + backward
+    # larger than the per-position (B, nc, Q, H) rows, which here end in
+    # (Q, H) = (256, 64) too
+    per_head = sorted(s for s in _ssd_step_shapes(text)
+                      if np.prod(s) > L * H
+                      and (s[-2:] in {(Q, P), (N, P)}
+                           or s[-3:] in {(H, Q, P), (Q, H, P)}))
+    assert not per_head
+    moved = [m.group(0) for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%(?:copy|transpose)(?!-start|-done)[\w.\-]* = "
+        r"f32\[([\d,]+)\]",
+        text, re.M)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= L * H * P]
+    assert not moved
 
 
 def test_flash_attention_forward_compiles_for_v5e(one_chip):

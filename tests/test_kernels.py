@@ -140,7 +140,7 @@ def test_flash_attention_ops_layout(key):
 # ------------------------------------------------------------------ SSD scan
 def _groups(G, H):
     """The B/C group count of a case: a number, or "H" for one per head."""
-    return H if G == "H" else G
+    return H if G == "H" else int(G)
 
 
 def _heads(a, H):
@@ -148,11 +148,26 @@ def _heads(a, H):
     return jnp.repeat(a, H // a.shape[2], axis=2)
 
 
+# (H, P) that tile into several head blocks of the kernel: hb = 2, 4 and 1
+_HEAD_BLOCKS = [(4, 64), (8, 32), (4, 128)]
+
+
+def _group_cases(H, P):
+    """G in {1, 2, "H"}: at (H, P) under the ids "1", "2", "H", then at each
+    _HEAD_BLOCKS shape."""
+    return [pytest.param(G, H, P, id=G) for G in ("1", "2", "H")] + [
+        pytest.param(G, h, p, id=f"{G}-H{h}-P{p}")
+        for h, p in _HEAD_BLOCKS for G in ("1", "2", "H")]
+
+
 @pytest.mark.parametrize("G", [1, 2, "H"])
 @pytest.mark.parametrize("L,H,P,N,chunk", [
     (64, 2, 16, 16, 16),
     (128, 4, 32, 32, 32),
     (96, 2, 16, 8, 32),          # L not a multiple of chunk*2
+    (64, 4, 64, 16, 16),         # two head blocks of 2
+    (64, 8, 32, 16, 16),         # two head blocks of 4
+    (64, 4, 128, 16, 32),        # one head a block
 ])
 def test_ssd_scan_vs_sequential_ref(L, H, P, N, chunk, G, key):
     G = _groups(G, H)
@@ -171,10 +186,10 @@ def test_ssd_scan_vs_sequential_ref(L, H, P, N, chunk, G, key):
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("G", [1, 2, "H"])
-def test_ssd_scan_initial_state(G, key):
+@pytest.mark.parametrize("G,H,P", _group_cases(4, 16))
+def test_ssd_scan_initial_state(G, H, P, key):
     """Chunked scan over [x1; x2] == scan x1 then scan x2 from its state."""
-    B, L, H, P, N = 1, 64, 4, 16, 16
+    B, L, N = 1, 64, 16
     G = _groups(G, H)
     k1, k2 = jax.random.split(key)
     x = jax.random.normal(k1, (B, L, H, P), jnp.float32)
@@ -228,12 +243,12 @@ def test_stream_triad_kernel(n, block, key):
 
 
 # ------------------------------------------------ SSD backward (custom VJP)
-@pytest.mark.parametrize("G", [1, 2, "H"])
-def test_ssd_kernel_gradients_match_reference(G, key):
+@pytest.mark.parametrize("G,H,P", _group_cases(4, 16))
+def test_ssd_kernel_gradients_match_reference(G, H, P, key):
     """jax.grad through the Pallas fwd+bwd kernels == grad of the
     sequential jnp recurrence; dB and dC come out at the group shape, equal
     to the reference's per-head gradients summed over each group's heads."""
-    B, L, H, P, N, chunk = 2, 64, 4, 16, 16, 16
+    B, L, N, chunk = 2, 64, 16, 16
     G = _groups(G, H)
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     x = jax.random.normal(k1, (B, L, H, P), jnp.float32) * 0.5
